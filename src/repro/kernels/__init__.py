@@ -2,6 +2,7 @@
 
 Layout per kernel: <name>.py holds the pl.pallas_call + BlockSpec tiling;
 ops.py is the dispatching wrapper (pallas | blockwise-jnp | ref); ref.py the
-pure-jnp oracle. Kernels validate in interpret=True mode on CPU.
+pure-jnp oracle. Kernels run in interpret mode off-TPU (their default
+there), and tests/test_tpu_compile.py compiles them for a described v5e.
 """
 from . import ops, ref

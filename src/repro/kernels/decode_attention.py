@@ -30,7 +30,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     kv_lo = j * kv_blk
 
     @pl.when(kv_lo < length)
@@ -63,12 +63,17 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             window: Optional[int] = None,
                             scale: Optional[float] = None,
                             kv_blk: int = 512,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Hq, D); k/v: (B, Hkv, S, D); length: (B,) int32 -> (B, Hq, D).
 
     With a ring-buffer window cache (S == window), all slots < length are
-    valid, so the same masking applies.
+    valid, so the same masking applies.  ``length`` is a scalar-prefetch
+    operand (SMEM, whole array): Mosaic refuses a rank-1 (1,) block of it.
+    ``interpret`` defaults to True exactly when the default backend is not
+    a TPU.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
     _, hkv, s, _ = k.shape
     g = h // hkv
@@ -83,21 +88,25 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(_kernel, scale=scale, kv_blk=kv_blk, n_kv=n_kv)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b_, h_, j: (b_,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, kv_blk, d), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, kv_blk, d), lambda b_, h_, j: (b_, h_, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h_, j: (b_, h_, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, g, d),
+                             lambda b_, h_, j, ln: (b_, h_, 0, 0)),
+                pl.BlockSpec((1, 1, kv_blk, d),
+                             lambda b_, h_, j, ln: (b_, h_, j, 0)),
+                pl.BlockSpec((1, 1, kv_blk, d),
+                             lambda b_, h_, j, ln: (b_, h_, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, g, d),
+                                   lambda b_, h_, j, ln: (b_, h_, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, d), jnp.float32),
+                pltpu.VMEM((g,), jnp.float32),
+                pltpu.VMEM((g,), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
         interpret=interpret,
     )(length.astype(jnp.int32), qg, k, v)
     return out.reshape(b, h, d)

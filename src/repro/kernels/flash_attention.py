@@ -11,8 +11,9 @@ Masked-out kv blocks (beyond the causal frontier or outside the sliding
 window) skip their compute via ``pl.when`` — on hardware those grid steps
 cost only the (prefetch-overlapped) DMA, giving the ~2x causal saving.
 
-Validated in ``interpret=True`` mode against ``ref.attention_ref`` (CPU has
-no Mosaic backend; see tests/test_kernels_pallas.py).
+Validated in interpret mode against ``ref.attention_ref`` (CPU has no
+Mosaic backend; see tests/test_kernels_pallas.py) and compiled for a
+described v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -86,8 +87,13 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            window: Optional[int] = None, offset: int = 0,
                            scale: Optional[float] = None,
                            q_blk: int = 256, kv_blk: int = 256,
-                           interpret: bool = True) -> jax.Array:
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D) -> (B, Hq, Sq, D)."""
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D) -> (B, Hq, Sq, D).
+
+    ``interpret`` defaults to True exactly when the default backend is not
+    a TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     g = h // hkv

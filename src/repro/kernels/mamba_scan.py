@@ -25,7 +25,7 @@ def _kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, h0_ref,
             y_ref, hT_ref, h_scr, *, t_len: int):
     h_scr[...] = h0_ref[0].astype(jnp.float32)          # (d_blk, N)
     A = A_ref[...].astype(jnp.float32)                  # (d_blk, N)
-    D = D_ref[...].astype(jnp.float32)                  # (d_blk,)
+    D = D_ref[0].astype(jnp.float32)                    # (d_blk,)
 
     def step(t, _):
         u_t = u_ref[0, t].astype(jnp.float32)           # (d_blk,)
@@ -46,8 +46,18 @@ def _kernel(u_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, h0_ref,
 def mamba_scan_pallas(u: jax.Array, dt: jax.Array, A: jax.Array,
                       B: jax.Array, C: jax.Array, D: jax.Array,
                       h0: Optional[jax.Array] = None,
-                      d_blk: int = 256, interpret: bool = True):
-    """Shapes as ref.mamba_scan_ref. Returns (y, h_T)."""
+                      d_blk: int = 256, interpret: Optional[bool] = None):
+    """Shapes as ref.mamba_scan_ref. Returns (y, h_T).
+
+    ``D`` travels as a (1, d_in) row tiled in (1, d_blk) blocks: a rank-1
+    block gives Mosaic a layout it refuses.  The kernel reads and writes
+    float32 (it computes in float32 anyway): the time loop touches one row
+    at a dynamic offset, which Mosaic allows for 32-bit rows but not for
+    packed bfloat16 ones; ``y`` is cast back to ``u.dtype`` after.
+    ``interpret`` defaults to True exactly when the default backend is not
+    a TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     bt, t, d_in = u.shape
     n = A.shape[1]
     d_blk = min(d_blk, d_in)
@@ -55,6 +65,8 @@ def mamba_scan_pallas(u: jax.Array, dt: jax.Array, A: jax.Array,
     n_d = d_in // d_blk
     if h0 is None:
         h0 = jnp.zeros((bt, d_in, n), jnp.float32)
+    out_dtype = u.dtype
+    u, dt, B, C = (a.astype(jnp.float32) for a in (u, dt, B, C))
     grid = (bt, n_d)
     kernel = functools.partial(_kernel, t_len=t)
     y, hT = pl.pallas_call(
@@ -66,7 +78,7 @@ def mamba_scan_pallas(u: jax.Array, dt: jax.Array, A: jax.Array,
             pl.BlockSpec((d_blk, n), lambda b_, i: (i, 0)),          # A
             pl.BlockSpec((1, t, n), lambda b_, i: (b_, 0, 0)),       # B
             pl.BlockSpec((1, t, n), lambda b_, i: (b_, 0, 0)),       # C
-            pl.BlockSpec((d_blk,), lambda b_, i: (i,)),              # D
+            pl.BlockSpec((1, d_blk), lambda b_, i: (0, i)),          # D
             pl.BlockSpec((1, d_blk, n), lambda b_, i: (b_, i, 0)),   # h0
         ],
         out_specs=[
@@ -74,10 +86,10 @@ def mamba_scan_pallas(u: jax.Array, dt: jax.Array, A: jax.Array,
             pl.BlockSpec((1, d_blk, n), lambda b_, i: (b_, i, 0)),   # hT
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bt, t, d_in), u.dtype),
+            jax.ShapeDtypeStruct((bt, t, d_in), jnp.float32),
             jax.ShapeDtypeStruct((bt, d_in, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d_blk, n), jnp.float32)],
         interpret=interpret,
-    )(u, dt, A, B, C, D, h0)
-    return y, hT
+    )(u, dt, A, B, C, D.reshape(1, d_in), h0)
+    return y.astype(out_dtype), hT

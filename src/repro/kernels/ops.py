@@ -6,7 +6,7 @@ Every op has three interchangeable implementations:
 * ``impl="jnp"``    — memory-bounded blockwise jnp (default off-TPU; this is
   what the multi-pod dry-run lowers, so compile-time memory analysis reflects
   flash-style tiling rather than materialised S^2 score matrices);
-* ``impl="pallas"`` — the Pallas TPU kernels (``interpret=True`` on CPU).
+* ``impl="pallas"`` — the Pallas TPU kernels (interpret mode off-TPU).
 
 The blockwise jnp path implements *causal block skipping*: for causal and
 sliding-window attention, key/value blocks that are entirely masked for a
@@ -21,8 +21,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import shard_map as _shard_map
 
 from . import ref as _ref
 
@@ -184,7 +182,7 @@ def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ba = None
     spec = P(ba, None, axis, None)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec), out_specs=spec,
                        check_vma=False)
     def f(ql, kl, vl):

@@ -7,6 +7,7 @@ the variance, once for the scale-multiply).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,12 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
-                   rows_blk: int = 256, interpret: bool = True) -> jax.Array:
+                   rows_blk: int = 256,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """Row-tiled RMSNorm over the last axis.  ``interpret`` defaults to True
+    exactly when the default backend is not a TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     orig_shape = x.shape
     d = orig_shape[-1]
     rows = x.size // d

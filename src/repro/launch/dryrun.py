@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run driver (deliverable e).
 
 For every (arch x input-shape x mesh) cell: build ShapeDtypeStruct inputs,
@@ -23,6 +21,7 @@ Cells already present in --out are skipped (resumable sweep).
 import argparse
 import dataclasses
 import json
+import os
 import re
 import sys
 import time
@@ -147,9 +146,8 @@ class CellResult:
 
 
 def _cost(compiled) -> Dict[str, float]:
-    from repro.compat import cost_analysis
     try:
-        c = cost_analysis(compiled)
+        c = compiled.cost_analysis()
         return {"flops": float(c.get("flops", 0.0)),
                 "bytes": float(c.get("bytes accessed", 0.0))}
     except Exception:
@@ -459,6 +457,11 @@ def lower_body_prefill(cfg, mesh, seq, batch, fsdp):
 
 
 def main():
+    # a compile-only tool over 512 virtual host devices: the flag must be
+    # in place before the first backend use, and the platform is the CPU
+    # even on a host with an accelerator (the mesh is never the local chip)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

@@ -1,27 +1,31 @@
 """Production mesh construction (multi-pod dry-run requirement).
 
 ``make_production_mesh`` is a FUNCTION so importing this module never touches
-jax device state.  The dry-run driver sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; smoke tests and benchmarks see the real single device.
+jax device state.  The dry-run's ``main()`` sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=512`` and the CPU
+platform before the first backend use; everything else sees the real
+devices.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh for tests/examples (e.g. (1, 1) on one CPU device)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests/examples (e.g. (1, 1) on one CPU device).
+
+    Axes are ``Auto``: the model code relies on GSPMD propagation (plus
+    ``partitioning.constrain`` hints), not on explicit-sharding types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_decode_step
 from repro.models import model_api
 
@@ -46,12 +47,10 @@ def serve_batch(cfg, params, requests: List[Request], max_len: int = 256,
     t0 = time.time()
     tok = jnp.asarray(pad[:, 0])
     outs = [[] for _ in range(b)]
-    last_logits = None
     # prefill (token-by-token; each step also warms the caches)
     for t in range(maxp):
-        nxt, logits, cache = step_fn(params, cache, jnp.asarray(pad[:, t]),
-                                     jnp.int32(t))
-        last_logits = logits
+        nxt, _, cache = step_fn(params, cache, jnp.asarray(pad[:, t]),
+                                jnp.int32(t))
     cur = np.asarray(nxt)
     max_new = max(r.max_new for r in requests)
     for t in range(maxp, maxp + max_new):
@@ -74,6 +73,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
     cfg = get(args.arch, smoke=True)
     api = model_api(cfg)
     params = api.init(jax.random.PRNGKey(0), cfg)

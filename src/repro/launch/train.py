@@ -17,11 +17,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.ckpt import checkpoint as ckpt
 from repro.configs import get
 from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.launch import shardings as SH
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import batch_axes, make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import frontends, model_api
@@ -47,16 +49,24 @@ def train(arch: str, smoke: bool = True, steps: int = 50, batch: int = 8,
                                     total=steps))
     key = jax.random.PRNGKey(seed)
     params = api.init(key, cfg)
-    opt_state = optimizer.init(params)
     p_shards = SH.param_shardings(cfg, params, mesh, fsdp=False)
     params = jax.device_put(params, p_shards)
+    # the moments inherit the param shardings; scalars (the step count) are
+    # replicated over the mesh, as the step returns them — otherwise the
+    # second step would compile again
+    opt_state = jax.tree.map(
+        lambda x: x if x.ndim else jax.device_put(x, NamedSharding(mesh, P())),
+        optimizer.init(params))
+    # the global batch splits over the data axes (data parallelism)
+    b_shard = NamedSharding(mesh, SH.batch_pspec(mesh, batch,
+                                                  pure_dp=cfg.pure_dp))
 
     source = SyntheticLM(batch, seq, cfg.vocab, seed=seed)
     start_step = 0
     if ckpt_dir:
         last = ckpt.latest_step(ckpt_dir)
         if last is not None:
-            opt_shards = jax.tree.map(lambda _: None, opt_state)
+            opt_shards = jax.tree.map(lambda x: x.sharding, opt_state)
             (params, opt_state), extra = ckpt.restore(
                 ckpt_dir, last, (params, opt_state),
                 shardings=(p_shards, opt_shards))
@@ -68,13 +78,14 @@ def train(arch: str, smoke: bool = True, steps: int = 50, batch: int = 8,
 
     step_fn = jax.jit(make_train_step(cfg, optimizer),
                       donate_argnums=(0, 1))
-    losses = []
+    losses, step_s = [], []
     t0 = time.time()
     with mesh:
         for step in range(start_step, steps):
+            ts = time.perf_counter()
             raw = data.next_batch()
-            b = {"inputs": jnp.asarray(raw["inputs"]),
-                 "labels": jnp.asarray(raw["labels"])}
+            b = jax.device_put({"inputs": raw["inputs"],
+                                "labels": raw["labels"]}, b_shard)
             if cfg.family == "vlm":
                 emb = frontends.image_patches(
                     jax.random.fold_in(key, step), cfg, batch)
@@ -85,7 +96,9 @@ def train(arch: str, smoke: bool = True, steps: int = 50, batch: int = 8,
             elif cfg.family == "audio":
                 b["frames"] = frontends.audio_frames(
                     jax.random.fold_in(key, step), cfg, batch)
-            params, opt_state, metrics = step_fn(params, opt_state, b)
+            params, opt_state, metrics = jax.block_until_ready(
+                step_fn(params, opt_state, b))
+            step_s.append(time.perf_counter() - ts)
             losses.append(float(metrics["loss"]))
             if step % log_every == 0 or step == steps - 1:
                 dt = time.time() - t0
@@ -99,7 +112,8 @@ def train(arch: str, smoke: bool = True, steps: int = 50, batch: int = 8,
     saver.join()
     data.close()
     part.set_mesh(None)
-    return {"losses": losses, "params": params, "cfg": cfg}
+    return {"losses": losses, "step_s": step_s, "params": params, "cfg": cfg,
+            "batch_sharding": b_shard}
 
 
 def main():
@@ -115,6 +129,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
+    use_compile_cache()
     out = train(args.arch, smoke=not args.full, steps=args.steps,
                 batch=args.batch, seq=args.seq, lr=args.lr,
                 ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
-
 
 def quantize_int8(x: jax.Array, key: jax.Array,
                   stochastic: bool = True) -> Tuple[jax.Array, jax.Array]:
@@ -78,7 +76,7 @@ def cross_pod_allreduce_int8(grads: Any, mesh, key: jax.Array,
     other_axes = tuple(a for a in mesh.axis_names if a != pod_axis)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P()), out_specs=P(),
         check_vma=False)
     def exchange(buf, k):

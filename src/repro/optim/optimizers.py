@@ -48,7 +48,7 @@ def adamw(lr: Callable | float, b1: float = 0.9, b2: float = 0.95,
     lr_fn = lr if callable(lr) else (lambda _: lr)
 
     def init(params):
-        zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+        zeros = lambda p: jnp.zeros_like(p, jnp.float32)   # keeps sharding
         return {"mu": jax.tree.map(zeros, params),
                 "nu": jax.tree.map(zeros, params),
                 "step": jnp.zeros((), jnp.int32)}

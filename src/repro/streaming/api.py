@@ -1038,11 +1038,11 @@ class Plan:
         in-process, cross-socket streams pay a real shared-memory
         serialize+copy), workers pinned to the socket's share of the host
         cores via ``os.sched_setaffinity``.  ``faithful=False`` gives every
-        replica its own worker.  ``env`` seeds extra environment variables
-        into each worker before kernels run (e.g.
-        :func:`~repro.streaming.procexec.host_device_env` for the JAX
-        host-device variant); ``timeout`` bounds the whole run — a wedged
-        ring fails fast instead of hanging.
+        replica its own worker.  Either way every device-operator replica
+        runs in one worker, the process that owns the chip.  ``env`` seeds
+        extra environment variables into each worker before kernels run;
+        ``timeout`` bounds the whole run — a wedged ring fails fast
+        instead of hanging.
 
         The plan's replication levels target the *modelled* machine; by
         default they are scaled down to ``max_threads`` (2x host cores)

@@ -49,12 +49,15 @@ parent restores them onto its own handles (:func:`_restore_state`) — so
 ``migrate_states`` and every downstream consumer of
 ``RuntimeResult.states`` work unchanged across process boundaries.
 
-The optional JAX host-device variant: pass
-``env=host_device_env(n)`` so each worker sees
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* any lazy
-JAX initialization — kernels that import JAX inside a worker then see N
-host devices.  (tcmalloc, per the exemplar run scripts, must be
-``LD_PRELOAD``-ed into the *parent* before Python starts: preloading
+Device operators (``device=True``) follow one rule: a chip belongs to one
+process at a time.  So every replica of every device operator runs in one
+worker, the only one that imports JAX; the parent and the other workers
+never do.  Default and plan-derived groupings are merged to honour this,
+and a ``groups=`` that names device replicas into two different groups
+raises :class:`DeviceGroupError`.  That worker sees its devices as they
+are; on the CPU a caller may pass ``env=host_device_env(n)`` to give it n
+XLA host devices.  (tcmalloc, per the exemplar run scripts, must
+be ``LD_PRELOAD``-ed into the *parent* before Python starts: preloading
 happens at exec time and forked workers inherit it — see docs/API.md.)
 """
 from __future__ import annotations
@@ -71,7 +74,7 @@ import traceback
 import multiprocessing as mp
 from multiprocessing import shared_memory
 from multiprocessing.connection import wait as conn_wait
-from typing import Callable, Dict, List, Mapping, Optional, Tuple
+from typing import Callable, Dict, List, Mapping, Optional, Set, Tuple
 
 import numpy as np
 
@@ -87,13 +90,16 @@ from .state import (BroadcastTable, EventTimeWindowState, KeyedStore,
 
 __all__ = ["ShmRing", "register_ring_dtype", "run_app_processes",
            "plan_placement", "socket_core_map", "host_device_env",
-           "get_backend", "register_backend", "BACKENDS"]
+           "DeviceGroupError", "get_backend", "register_backend", "BACKENDS"]
 
-_SLOT_BYTES = 128 * 1024     # default ring slot: comfortably holds the
-# largest benchmark jumbo (WC's splitter emits batch x 10 int64 words —
-# 80 KiB at batch 1024) with headroom; oversize payloads raise with a
+_SLOT_BYTES = 128 * 1024     # smallest default ring slot: holds WC's
+# splitter jumbo (batch x 10 int64 words — 80 KiB at batch 1024) with
+# headroom.  Each edge's default slot also fits two batches of its
+# consumer's declared tuple width (a jumbo that overflows its lane is
+# concatenated to under two batches); oversize payloads raise with a
 # pointer at slot_bytes= instead of splitting the batch (a split would
 # change stateful kernels' running outputs and break byte parity)
+_SLOT_SLACK = 1024           # slot header, lane tag and pickle framing
 _RING_SLOTS = 8              # slots per ring (jumbos in flight per lane)
 _CTRL = 16                   # ring header: head int64 @0, tail int64 @8
 _POLL = 50e-6                # idle poll quantum (grows to _POLL_MAX)
@@ -630,20 +636,60 @@ def plan_placement(plan, parallelism: Dict[str, int]
         s = sorted(max(0, x) for x in (placed or [0]))  # UNPLACED -> 0
         for j in range(k):
             groups[(op, j)] = s[j % len(s)]
+    # device replicas share the one worker that owns the chip: the socket
+    # of the first device replica
+    device_ops = [op for op in parallelism
+                  if plan.job.graph.operators[op].device]
+    if device_ops:
+        home = groups[(device_ops[0], 0)]
+        for op in device_ops:
+            for j in range(parallelism[op]):
+                groups[(op, j)] = home
     pins = socket_core_map(plan.machine.n_sockets)
     return groups, pins
 
 
+class DeviceGroupError(ValueError):
+    """Device-operator replicas were assigned to more than one worker.
+
+    A chip belongs to one process at a time, so every replica of every
+    ``device=True`` operator must run in the one worker that owns it."""
+
+
+def _colocate_device_replicas(group_of: Dict[Replica, object],
+                              device_ops: Mapping[str, int],
+                              par: Mapping[str, int],
+                              named: Set[object]) -> None:
+    """Put every device-operator replica in one worker group, in place.
+
+    Every group holding a device replica is merged into the one the caller
+    named for them (a group id in ``named``), else into the first such
+    group.  Device replicas the caller named into two different groups
+    raise :class:`DeviceGroupError` instead of being overridden."""
+    dev = list(dict.fromkeys(group_of[(op, i)] for op in device_ops
+                             for i in range(par[op])))
+    asked = [g for g in dev if g in named]
+    if len(asked) > 1:
+        raise DeviceGroupError(
+            f"device operators {sorted(device_ops)} span worker groups "
+            f"{asked}: the chip belongs to one process, so put every "
+            "device replica in one group")
+    home = asked[0] if asked else dev[0]
+    for rep, g in group_of.items():
+        if g in dev:
+            group_of[rep] = home
+
+
 def host_device_env(n: int, base: Optional[Mapping[str, str]] = None
                     ) -> Dict[str, str]:
-    """Worker environment for the JAX host-device variant.
+    """Worker environment for JAX on the CPU.
 
     Sets ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (replacing
     any existing count flag) so a kernel that lazily imports JAX inside a
-    worker sees N host devices — one per pinned core group.  Also sets the
-    tcmalloc large-alloc report threshold the exemplar run scripts use;
-    tcmalloc itself must be LD_PRELOAD-ed into the *parent* (preloading
-    happens at exec, forked workers inherit it — see docs/API.md)."""
+    worker sees N host devices.  Also sets the tcmalloc large-alloc report
+    threshold the exemplar run scripts use; tcmalloc itself must be
+    LD_PRELOAD-ed into the *parent* (preloading happens at exec, forked
+    workers inherit it — see docs/API.md)."""
     env = dict(base or {})
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
@@ -669,7 +715,7 @@ def run_app_processes(app: StreamingApp,
                       groups: Optional[Mapping] = None,
                       pin: Optional[Mapping[object, List[int]]] = None,
                       env: Optional[Mapping[str, str]] = None,
-                      slot_bytes: int = _SLOT_BYTES,
+                      slot_bytes: Optional[int] = None,
                       ring_slots: int = _RING_SLOTS,
                       ring_format: str = "raw",
                       timeout: Optional[float] = None,
@@ -687,7 +733,9 @@ def run_app_processes(app: StreamingApp,
     -> worker group id; default one worker per replica), ``pin`` (group id
     -> CPU cores, applied via ``sched_setaffinity``), ``env`` (extra
     worker environment), ``slot_bytes``/``ring_slots``/``ring_format``
-    (ring geometry and slot encoding — ``"raw"`` is the zero-copy default,
+    (ring geometry and slot encoding — by default each edge's slot fits two
+    batches of its consumer's declared ``tuple_bytes``, at least 128 KiB;
+    ``"raw"`` is the zero-copy default,
     ``"pickle"`` forces the fallback path everywhere for serialization
     A/Bs) and ``timeout`` (whole-run deadline; on expiry workers are
     terminated, every shared-memory segment is unlinked and
@@ -739,8 +787,8 @@ def run_app_processes(app: StreamingApp,
         for m in chain[1:]:
             for i in range(par[head]):
                 group_of[(m, i)] = group_of[(head, i)]
-    gids = list(dict.fromkeys(group_of.values()))      # first-appearance order
-    if getattr(app, "device_ops", None) and app.device_ops():
+    device_ops = app.device_ops() if getattr(app, "device_ops", None) else {}
+    if device_ops:
         # forking after the parent has initialized JAX/XLA deadlocks the
         # child's first jit call (multithreaded runtime + fork) — fail fast
         # with the workaround instead of hanging the run
@@ -751,11 +799,9 @@ def run_app_processes(app: StreamingApp,
                 "inherit XLA's thread state and deadlock on first jit "
                 "call). Run device apps from a fresh process, or use "
                 "backend='threads'")
-        # first real kernel user of the host-device plumbing: each worker
-        # group gets an XLA host device unless the caller already set one
-        if not any("--xla_force_host_platform_device_count" in v
-                   for v in (env or {}).values()):
-            env = host_device_env(max(1, len(gids)), base=env)
+        _colocate_device_replicas(group_of, device_ops, par,
+                                  set(groups.values()) if groups else set())
+    gids = list(dict.fromkeys(group_of.values()))      # first-appearance order
     members: Dict[object, List[Replica]] = {g: [] for g in gids}
     for rep in replicas:
         members[group_of[rep]].append(rep)
@@ -769,6 +815,8 @@ def run_app_processes(app: StreamingApp,
     for v in lg.operators:
         if lg.operators[v].is_spout:
             continue
+        v_slot = slot_bytes or max(_SLOT_BYTES, _SLOT_SLACK + 2 * batch
+                                   * math.ceil(lg.operators[v].tuple_bytes))
         for j in range(par[v]):
             for u in lg.producers(v):
                 if (u, v) in intra:
@@ -780,7 +828,7 @@ def run_app_processes(app: StreamingApp,
                             local_qs[cr] = queue.Queue(maxsize=queue_cap)
                     else:
                         rings[(pr, cr)] = ShmRing(
-                            capacity=ring_cap, slot_bytes=slot_bytes,
+                            capacity=ring_cap, slot_bytes=v_slot,
                             raw=ring_format == "raw")
 
     ctrl = shared_memory.SharedMemory(name=_ring_name(), create=True, size=16)

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import compiled_flops
 from repro.kernels import ops, ref
 
 
@@ -46,7 +45,7 @@ def test_flash_jnp_block_skipping_reduces_flops():
     def cost(causal):
         fn = lambda q, k, v: ops.flash_attention(
             q, k, v, causal=causal, impl="jnp", q_chunk=64, kv_chunk=64)
-        return compiled_flops(jax.jit(fn).lower(q, k, v).compile())
+        return jax.jit(fn).lower(q, k, v).compile().cost_analysis()["flops"]
 
     assert cost(True) < 0.65 * cost(False)
 

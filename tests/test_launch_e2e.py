@@ -126,3 +126,20 @@ print("OK")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=540)
     assert "OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache sits at the fixed <checkout>/.jax_cache."""
+    from repro.launch.compile_cache import use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(REPO, ".jax_cache")
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -9,8 +9,11 @@ protocol, crashes and wedges tear down without orphaning ``/dev/shm``
 segments, state migrates across a process-backend replan byte-for-byte,
 and plan-faithful grouping realizes the plan's socket map.
 """
+import json
 import os
 import queue
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -19,7 +22,8 @@ import pytest
 from repro.core import server_a, subset
 from repro.streaming.api import Job, Topology
 from repro.streaming.apps import (linear_road, spike_detection_eventtime,
-                                  spike_detection_keyed, word_count)
+                                  spike_detection_keyed, streaming_inference,
+                                  word_count)
 from repro.streaming.procexec import (BACKENDS, ShmRing, get_backend,
                                       host_device_env, plan_placement,
                                       register_backend, register_ring_dtype,
@@ -30,7 +34,9 @@ from repro.streaming.state import (KeyedStore, StateSpec, WindowSpec,
 
 
 def _shm_leftovers():
-    return [f for f in os.listdir("/dev/shm") if f.startswith("bsr")]
+    # only this process's segments: other test processes' rings are live
+    return [f for f in os.listdir("/dev/shm")
+            if f.startswith(f"bsr{os.getpid()}x")]
 
 
 def _summary(r):
@@ -323,6 +329,79 @@ def test_plan_placement_groups_follow_socket_map():
     assert set().union(*pins.values()) <= set(os.sched_getaffinity(0))
 
 
+def test_plan_placement_colocates_device_replicas():
+    plan = Job(streaming_inference(model_versions=1)).plan(
+        server_a(), optimizer="rlas", compress_ratio=5, bestfit=True,
+        max_nodes=5000)
+    par = dict(plan.parallelism)
+    assert par["predictor"] > 1 and len(set(plan.placement)) > 1
+    groups, _ = plan_placement(plan, par)
+    assert len({groups[("predictor", j)]
+                for j in range(par["predictor"])}) == 1
+
+
+_DEVICE_GROUP_CHILD = """
+import json, os, sys
+import numpy as np
+from repro.streaming.api import Topology
+from repro.streaming.procexec import DeviceGroupError, run_app_processes
+
+def k_device(batch, state):
+    import jax.numpy as jnp
+    state["pid"] = os.getpid()
+    return [jnp.asarray(batch) * 2.0]
+
+def k_sink(batch, state):
+    state["pid"] = os.getpid()
+    state["jax"] = "jax" in sys.modules
+    state["seen"] = state.get("seen", 0) + len(batch)
+    return []
+
+app = (Topology("dev")
+       .spout("s", lambda b, sd: np.random.default_rng(sd).normal(size=b),
+              exec_ns=100.0)
+       .op("d", k_device, exec_ns=100.0, device=True, device_ns=100.0)
+       .sink("k", k_sink, exec_ns=50.0)
+       .build())
+r = run_app_processes(app, {"d": 3}, batch=16, max_batches=6, timeout=120.0)
+# naming only host operators leaves the device replicas free to share
+part = run_app_processes(app, {"d": 2}, batch=16, max_batches=2,
+                         groups={"s": 0, "k": 0}, timeout=120.0)
+try:
+    run_app_processes(app, {"d": 2}, batch=16, max_batches=2,
+                      groups={("d", 0): 0, ("d", 1): 1})
+    split = "accepted"
+except DeviceGroupError as e:
+    split = str(e)
+print(json.dumps({"dev_pids": [st["pid"] for st in r.states["d"]],
+                  "part_pids": [st["pid"] for st in part.states["d"]],
+                  "sink_pid": r.states["k"][0]["pid"],
+                  "sink_jax": r.states["k"][0]["jax"],
+                  "seen": r.states["k"][0]["seen"],
+                  "parent_jax": "jax" in sys.modules, "split": split}))
+"""
+
+
+def test_device_replicas_share_one_worker():
+    """Every device replica runs in the one worker that imports JAX (the
+    chip belongs to one process), also when ``groups=`` names only host
+    operators; a split the caller names is refused."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    cp = subprocess.run([sys.executable, "-c", _DEVICE_GROUP_CHILD],
+                        capture_output=True, text=True, env=env, timeout=240)
+    assert cp.returncode == 0, cp.stderr[-2000:]
+    out = json.loads(cp.stdout.strip().splitlines()[-1])
+    assert len(set(out["dev_pids"])) == 1 and len(out["dev_pids"]) == 3
+    assert out["sink_pid"] != out["dev_pids"][0]
+    assert not out["sink_jax"] and not out["parent_jax"]
+    assert len(set(out["part_pids"])) == 1 and len(out["part_pids"]) == 2
+    assert out["seen"] == 6 * 16
+    assert "span worker groups" in out["split"]
+
+
 # ---------------------------------------------------------------------------
 # State across process boundaries: migration round trip
 # ---------------------------------------------------------------------------
@@ -398,6 +477,24 @@ def test_wedged_worker_times_out_fast_and_cleans_up():
 # ---------------------------------------------------------------------------
 # Worker environment: pinning, env injection, the JAX host-device variant
 # ---------------------------------------------------------------------------
+
+def test_ring_slots_fit_wide_batches():
+    """A (1024, 32) float32 jumbo is 128 KiB of rows plus a header: the
+    default slot fits two batches of the consumer's declared width."""
+    app = (Topology("wide")
+           .spout("s", lambda b, sd: np.random.default_rng(sd).normal(
+               size=(b, 32)).astype(np.float32), exec_ns=100.0,
+               tuple_bytes=128.0)
+           .op("work", lambda b, st: [b], exec_ns=100.0, tuple_bytes=128.0)
+           .sink("sink", lambda b, st: st.__setitem__(
+               "seen", st.get("seen", 0) + len(b)) or [], exec_ns=50.0,
+               tuple_bytes=128.0)
+           .build())
+    r = run_app_processes(app, batch=1024, max_batches=3, seed=0,
+                          timeout=60.0)
+    assert r.states["sink"][0]["seen"] == 3 * 1024
+    assert not _shm_leftovers()
+
 
 def test_env_and_affinity_reach_the_worker():
     def observer(batch, state):

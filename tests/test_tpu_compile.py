@@ -1,0 +1,88 @@
+"""The Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret-mode tests (test_kernels_pallas.py) check numerics but never meet
+Mosaic's tiling and layout rules.  Here each kernel is lowered with
+``interpret=False`` against a *described* v5e (no chip attached) and
+compiled by the TPU compiler that ships with libtpu.  Nothing runs, so
+these tests say nothing about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load libtpu, and the test runner's workers all
+import this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.mamba_scan import mamba_scan_pallas
+from repro.kernels.rmsnorm import rmsnorm_pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:              # no libtpu, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep it out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def test_flash_attention_compiles_at_smollm_widths(one_chip):
+    # smollm-360m: 15 query heads over 5 kv heads, head_dim 64
+    _compile(lambda q, k, v: flash_attention_pallas(q, k, v, interpret=False),
+             [((1, 15, 2048, 64), BF16), ((1, 5, 2048, 64), BF16),
+              ((1, 5, 2048, 64), BF16)], one_chip)
+
+
+def test_decode_attention_compiles(one_chip):
+    _compile(lambda q, k, v, n: decode_attention_pallas(
+                 q, k, v, length=n, interpret=False),
+             [((8, 15, 64), BF16), ((8, 5, 4096, 64), BF16),
+              ((8, 5, 4096, 64), BF16), ((8,), I32)], one_chip)
+
+
+def test_rmsnorm_compiles(one_chip):
+    _compile(lambda x, s: rmsnorm_pallas(x, s, interpret=False),
+             [((4096, 960), BF16), ((960,), BF16)], one_chip)
+
+
+def test_mamba_scan_compiles_at_jamba_slice(one_chip):
+    # Jamba-1.5: d_inner 16384, state 16; one 256-step slice of one sequence
+    t, d_in, n = 256, 16384, 16
+    _compile(lambda u, dt, a, b, c, d: mamba_scan_pallas(
+                 u, dt, a, b, c, d, interpret=False),
+             [((1, t, d_in), BF16), ((1, t, d_in), BF16), ((d_in, n), F32),
+              ((1, t, n), BF16), ((1, t, n), BF16), ((d_in,), F32)],
+             one_chip)
