@@ -22,6 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from . import ref as _ref
 
 NEG_INF = -1e30
@@ -136,14 +137,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``offset``: absolute position of q[0] relative to kv[0] (prefill chunks).
     """
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    if impl == "ref":
-        return _ref.attention_ref(q, k, v, causal, window, offset, scale)
-    if impl == "pallas":
-        from .flash_attention import flash_attention_pallas
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      offset=offset, scale=scale)
-    return _flash_jnp(q, k, v, causal, window, offset, scale, q_chunk,
-                      kv_chunk)
+    with obs.scope("attention"):
+        if impl == "ref":
+            return _ref.attention_ref(q, k, v, causal, window, offset, scale)
+        if impl == "pallas":
+            from .flash_attention import flash_attention_pallas
+            return flash_attention_pallas(q, k, v, causal=causal,
+                                          window=window, offset=offset,
+                                          scale=scale)
+        return _flash_jnp(q, k, v, causal, window, offset, scale, q_chunk,
+                          kv_chunk)
 
 
 def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -200,7 +203,8 @@ def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return _flash_jnp(ql, kg, vg, causal, window, r * L, scale,
                           q_chunk, kv_chunk, kv_valid_lo=lo)
 
-    return f(q, k, v)
+    with obs.scope("attention"):
+        return f(q, k, v)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,

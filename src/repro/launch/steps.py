@@ -7,6 +7,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import ModelAPI, model_api
 from repro.models.config import ModelConfig
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
@@ -15,6 +16,7 @@ from repro.optim.optimizers import Optimizer, clip_by_global_norm
 def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
                     clip_norm: float = 1.0) -> Callable:
     api = model_api(cfg)
+    obs.log_compiles()
 
     def train_step(params, opt_state, batch):
         def loss_fn(p):
@@ -23,8 +25,9 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        params, opt_state = optimizer.update(grads, opt_state, params)
+        with obs.scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            params, opt_state = optimizer.update(grads, opt_state, params)
         out = {"loss": loss, "grad_norm": gnorm}
         out.update({k: v for k, v in metrics.items()})
         return params, opt_state, out

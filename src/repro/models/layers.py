@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops
 from .config import ModelConfig
 from .module import dense_init
@@ -23,7 +24,8 @@ def rmsnorm_init(d: int) -> jax.Array:
 
 
 def rmsnorm(x, scale, eps):
-    return ops.rmsnorm(x, scale, eps)
+    with obs.scope("norm"):
+        return ops.rmsnorm(x, scale, eps)
 
 
 # --------------------------------------------------------------------------
@@ -66,13 +68,14 @@ def attn_apply(p, x, cfg: ModelConfig, positions, causal=True,
     """Full-sequence attention. x: (B, S, D) -> (B, S, D)."""
     b, s, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = (x @ p["wq"]).reshape(b, s, h, hd)
-    k = (x @ p["wk"]).reshape(b, s, hkv, hd)
-    v = (x @ p["wv"]).reshape(b, s, hkv, hd)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    with obs.scope("attn_proj"):
+        q = (x @ p["wq"]).reshape(b, s, h, hd)
+        k = (x @ p["wk"]).reshape(b, s, hkv, hd)
+        v = (x @ p["wv"]).reshape(b, s, hkv, hd)
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
     from . import partitioning as part
     mesh = part._STATE["mesh"]
     if cfg.seq_shard and causal and mesh is not None and \
@@ -85,8 +88,9 @@ def attn_apply(p, x, cfg: ModelConfig, positions, causal=True,
         out = ops.flash_attention(
             qt, kt, vt, causal=causal, window=cfg.window,
             impl=cfg.attn_impl, q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    return out @ p["wo"]
+    with obs.scope("attn_proj"):
+        out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+        return out @ p["wo"]
 
 
 def cross_attn_apply(p, x, kv_cache, cfg: ModelConfig) -> jax.Array:
@@ -281,4 +285,5 @@ def mlp_init(key, cfg: ModelConfig, dtype, d_ff: Optional[int] = None) -> Dict:
 
 
 def mlp_apply(p, x) -> jax.Array:
-    return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]
+    with obs.scope("mlp"):
+        return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]

@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from . import layers as L
 from . import moe as M
 from . import ssm as S
@@ -202,20 +203,23 @@ def _chunked_ce(params, h, labels, mask, cfg: ModelConfig,
     b, s, d = h.shape
     chunk = min(chunk, s)
     tot, cnt = jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
-    for i in range(0, s, chunk):
-        # final chunk may be ragged (e.g. the MTP branch's shifted sequence)
-        lg = logits_fn(params, h[:, i:i + chunk], cfg)       # (B, c, V) f32
-        lab = labels[:, i:i + chunk]
-        msk = mask[:, i:i + chunk]
-        lse = jax.nn.logsumexp(lg, axis=-1)
-        gold = jnp.take_along_axis(lg, lab[..., None], axis=-1)[..., 0]
-        tot += jnp.sum((lse - gold) * msk)
-        cnt += jnp.sum(msk)
+    with obs.scope("head_loss"):
+        for i in range(0, s, chunk):
+            # final chunk may be ragged (e.g. the MTP branch's shifted
+            # sequence)
+            lg = logits_fn(params, h[:, i:i + chunk], cfg)   # (B, c, V) f32
+            lab = labels[:, i:i + chunk]
+            msk = mask[:, i:i + chunk]
+            lse = jax.nn.logsumexp(lg, axis=-1)
+            gold = jnp.take_along_axis(lg, lab[..., None], axis=-1)[..., 0]
+            tot += jnp.sum((lse - gold) * msk)
+            cnt += jnp.sum(msk)
     return tot, cnt
 
 
 def embed_tokens(params, tokens, cfg: ModelConfig) -> jax.Array:
-    return params["embed"][tokens]
+    with obs.scope("embed"):
+        return params["embed"][tokens]
 
 
 def lm_loss(params, batch, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:

@@ -2,6 +2,7 @@
 and a real dry-run cell in a 512-device subprocess."""
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -107,6 +108,7 @@ def test_cp_attention_multishard_subprocess():
     """Ring CP attention numerics on a real 8-shard mesh."""
     code = '''
 import os
+import re
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "%s")
 import jax, numpy as np
@@ -130,16 +132,33 @@ print("OK")
 
 def test_compile_cache_dir(monkeypatch):
     """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
-    cache sits at the fixed <checkout>/.jax_cache."""
-    from repro.launch.compile_cache import use_compile_cache
+    cache sits at the fixed <checkout>/.jax_cache.  Either way the cache is
+    keyed with metadata whose source files are named from the checkout's
+    root, and importing the module sets that for a JAX imported later."""
+    from repro.launch.compile_cache import SOURCE_PREFIX, use_compile_cache
     prev = jax.config.jax_compilation_cache_dir
+    prev_meta = jax.config.jax_compilation_cache_include_metadata_in_key
+    prev_regex = jax.config.jax_hlo_source_file_canonicalization_regex
+    key = "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"
+    assert os.environ.get(key, "").lower() in ("true", "1")
+    assert re.sub(SOURCE_PREFIX, "", os.path.join(REPO, "src", "x.py")) \
+        == os.path.join("src", "x.py")
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
         assert use_compile_cache() == "/elsewhere"
         assert jax.config.jax_compilation_cache_dir == prev
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert jax.config.jax_hlo_source_file_canonicalization_regex \
+            == SOURCE_PREFIX
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         want = os.path.join(REPO, ".jax_cache")
         assert use_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          prev_meta)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          prev_regex)
