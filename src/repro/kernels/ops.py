@@ -59,7 +59,10 @@ def _attend_block(qg, kc, vc, qpos, kpos, causal, window, scale, state,
     if kv_valid_lo is not None:          # traced lower bound (CP ring edges)
         mask = mask & (kpos[None, :] >= kv_valid_lo)
     logits = jnp.where(mask[None, None, None], logits, NEG_INF)
-    m_new = jnp.maximum(m, logits.max(axis=-1))
+    # The running max only shifts the exponent, and softmax is unchanged by
+    # a shift of its argument, so the output's gradient through m is zero.
+    # Held constant, the backward keeps no max-location mask per block.
+    m_new = jax.lax.stop_gradient(jnp.maximum(m, logits.max(axis=-1)))
     corr = jnp.exp(m - m_new)
     p = jnp.exp(logits - m_new[..., None])
     l_new = l * corr + p.sum(axis=-1)

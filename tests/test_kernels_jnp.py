@@ -11,14 +11,16 @@ def rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, jnp.float32).astype(dtype)
 
 
-@pytest.mark.parametrize("b,hq,hkv,sq,skv,d", [
+SHAPES = [
     (1, 4, 4, 64, 64, 32),
     (2, 8, 2, 128, 128, 64),       # GQA 4:1
     (1, 4, 1, 64, 256, 32),        # MQA, kv longer than q (prefill tail)
-])
-@pytest.mark.parametrize("causal,window", [
-    (True, None), (False, None), (True, 32),
-])
+]
+MASKS = [(True, None), (False, None), (True, 32)]
+
+
+@pytest.mark.parametrize("b,hq,hkv,sq,skv,d", SHAPES)
+@pytest.mark.parametrize("causal,window", MASKS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_jnp_matches_ref(b, hq, hkv, sq, skv, d, causal, window, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -34,6 +36,83 @@ def test_flash_jnp_matches_ref(b, hq, hkv, sq, skv, d, causal, window, dtype):
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), atol=tol, rtol=tol)
+
+
+def _grads(fn, q, k, v, ct):
+    """Gradients of <fn(q, k, v), ct> with respect to q, k and v."""
+    loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_grads_close(got, exp, tol):
+    for g, e in zip(got, exp):
+        g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
+        np.testing.assert_allclose(g, e, rtol=tol,
+                                   atol=tol * float(np.abs(e).max()))
+
+
+@pytest.mark.parametrize("b,hq,hkv,sq,skv,d", SHAPES + [
+    (1, 4, 2, 48, 100, 32),        # ragged kv: padded columns masked
+])
+@pytest.mark.parametrize("causal,window", MASKS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_jnp_grad_matches_ref(b, hq, hkv, sq, skv, d, causal, window,
+                                    dtype):
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    offset = skv - sq
+    q = rand(ks[0], (b, hq, sq, d), dtype)
+    k = rand(ks[1], (b, hkv, skv, d), dtype)
+    v = rand(ks[2], (b, hkv, skv, d), dtype)
+    ct = rand(ks[3], (b, hq, sq, d))
+    got = _grads(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=causal, window=window, offset=offset, impl="jnp",
+        q_chunk=32, kv_chunk=64), q, k, v, ct)
+    exp = _grads(lambda q, k, v: ref.attention_ref(
+        q, k, v, causal=causal, window=window, offset=offset), q, k, v, ct)
+    _assert_grads_close(got, exp, 2e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 48])
+def test_flash_jnp_grad_with_kv_valid_lo(window):
+    """The traced lower bound of the context-parallel ring: columns below
+    it get no gradient, the rest match the oracle on the kept columns."""
+    b, h, hkv, sq, skv, d, lo = 1, 4, 2, 64, 128, 32, 40
+    offset = skv - sq
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = rand(ks[0], (b, h, sq, d))
+    k = rand(ks[1], (b, hkv, skv, d))
+    v = rand(ks[2], (b, hkv, skv, d))
+    ct = rand(ks[3], (b, h, sq, d))
+    got = _grads(lambda q, k, v: ops._flash_jnp(
+        q, k, v, True, window, offset, d ** -0.5, 32, 32,
+        kv_valid_lo=jnp.int32(lo)), q, k, v, ct)
+    dq, dk, dv = _grads(lambda q, k, v: ref.attention_ref(
+        q, k, v, causal=True, window=window, offset=offset - lo),
+        q, k[:, :, lo:], v[:, :, lo:], ct)
+    zeros = jnp.zeros((b, hkv, lo, d))
+    exp = (dq, jnp.concatenate([zeros, dk], 2),
+           jnp.concatenate([zeros, dv], 2))
+    _assert_grads_close(got, exp, 2e-5)
+
+
+def test_flash_jnp_grad_keeps_no_max_location_mask():
+    """The running max is held constant in the backward, so the gradient
+    program builds no ``argmax`` location mask (an EQ compare yielding a
+    pred of the score-block shape) for ``logits.max``."""
+    b, h, hkv, s, d, chunk = 1, 4, 2, 128, 32, 64
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = rand(ks[0], (b, h, s, d))
+    k = rand(ks[1], (b, hkv, s, d))
+    v = rand(ks[2], (b, hkv, s, d))
+    attn = jax.checkpoint(lambda q, k, v: ops._flash_jnp(
+        q, k, v, True, None, 0, d ** -0.5, chunk, chunk))
+    loss = lambda q, k, v: jnp.sum(attn(q, k, v))
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile().as_text()
+    block = f"pred[{b},{hkv},{h // hkv},{chunk},{chunk}]"
+    masks = [ln for ln in hlo.splitlines()
+             if block in ln and "compare(" in ln and "direction=EQ" in ln]
+    assert not masks, f"{len(masks)} max-location masks, e.g. {masks[0]}"
 
 
 def test_flash_jnp_block_skipping_reduces_flops():
