@@ -11,6 +11,7 @@ ARCHS = [
     "h2o_danube_1_8b", "smollm_360m", "granite_3_2b", "stablelm_3b",
     "xlstm_125m", "llava_next_mistral_7b", "jamba_1_5_large_398b",
     "whisper_small", "qwen3_moe_235b_a22b", "deepseek_v3_671b",
+    "moonlight_16b_a3b",
 ]
 
 

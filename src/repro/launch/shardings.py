@@ -29,7 +29,8 @@ from .mesh import batch_axes, fsdp_axis
 
 NORMS = {"ln1", "ln2", "ln_x", "final_norm", "enc_norm", "norm", "q_norm",
          "kv_norm", "norm_h", "norm_e"}
-REPLICATED = NORMS | {"b", "gate_bias", "dt_bias", "router", "w_gates",
+REPLICATED = NORMS | {"b", "gate_bias", "dt_bias", "router", "router_bias",
+                      "w_gates",
                       "enc_pos", "dec_pos", "r", "wkr"}
 ATTN_QKV = {"wq", "wk", "wv", "wuq", "wukv", "wdq", "wdkv"}
 

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.models import ModelAPI, model_api
+from repro.models import ModelAPI, model_api, moe
 from repro.models.config import ModelConfig
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
 
@@ -25,9 +25,14 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
+        loads = metrics.pop("moe_load", None)
         with obs.scope("optimizer"):
             grads, gnorm = clip_by_global_norm(grads, clip_norm)
-            params, opt_state = optimizer.update(grads, opt_state, params)
+            new_params, opt_state = optimizer.update(grads, opt_state, params)
+            if loads:       # the routers' correction biases: state, no grad
+                new_params = moe.update_router_bias(new_params, params,
+                                                    loads, cfg)
+        params = new_params
         out = {"loss": loss, "grad_norm": gnorm}
         out.update({k: v for k, v in metrics.items()})
         return params, opt_state, out

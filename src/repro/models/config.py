@@ -32,13 +32,23 @@ class ModelConfig:
     max_seq: int = 8192               # learned-positions budget (enc-dec only)
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0                # experts this layer holds: [0, n)
+    router_experts: int = 0           # router outputs, as published; 0 -> n_experts
     top_k: int = 0
     d_expert: int = 0
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
     first_k_dense: int = 0            # DeepSeek: dense FFN for first k layers
-    router_aux_weight: float = 0.001
+    router_aux_weight: float = 0.001  # balance loss weight (alpha)
+    scoring: str = "softmax"          # softmax | sigmoid (DeepSeek-V3)
+    router_bias: bool = False         # correction bias for selection only
+                                      # (DeepSeek-V3 noaux_tc)
+    router_bias_speed: float = 0.001  # gamma of the bias's load rule
+    routed_scale: float = 1.0         # routed_scaling_factor on the gates
+    n_group: int = 1                  # group-limited selection: groups,
+    topk_group: int = 1               # and groups kept per token
+    seq_aux: bool = False             # sequence-wise balance loss (DeepSeek-
+                                      # V3), else switch-style over the batch
     moe_dispatch_groups: int = 1      # grouped local dispatch (H3, a2a-shaped)
     moe_combine_dtype: str = "float32"  # scatter-add accumulator (H3 iter-3:
                                         # bfloat16 halves combine traffic)
@@ -91,10 +101,22 @@ class ModelConfig:
             f"period {len(self.period)}"
         if self.first_k_dense:
             assert len(self.period) == 1, "dense prefix needs uniform period"
+        if self.n_experts:
+            assert self.scoring in ("softmax", "sigmoid"), self.scoring
+            assert self.n_experts <= self.n_router, \
+                f"{self.name}: holds more experts than its router has"
+            assert self.n_router % self.n_group == 0, \
+                f"{self.name}: {self.n_group} groups of {self.n_router}"
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def n_router(self) -> int:
+        """The router's width: every expert of the layer, held here or
+        not."""
+        return self.router_experts or self.n_experts
 
     @property
     def n_periods(self) -> int:
@@ -122,7 +144,7 @@ class ModelConfig:
     def param_count(self) -> Tuple[int, int]:
         """(total_params, active_params_per_token)."""
         d, v = self.d_model, self.vocab
-        total = v * d * (1 if self.tie_embeddings else 2)
+        total = v * d * (1 if self.tie_embeddings else 2) + d   # final norm
         active = total
         for mixer, ffn in self.blocks():
             pm = self._mixer_params(mixer)
@@ -133,10 +155,15 @@ class ModelConfig:
                 total += pf
                 active += pf
             elif ffn == "moe":
+                # the experts held here; a token's routed share of them
                 pe = 3 * d * self.d_expert
-                total += self.n_experts * pe + d * self.n_experts
-                active += (self.top_k + self.n_shared_experts) * pe
-                total += self.n_shared_experts * pe
+                router = d * self.n_router + (self.n_router
+                                              if self.router_bias else 0)
+                total += (self.n_experts + self.n_shared_experts) * pe
+                total += router
+                active += pe * (
+                    self.top_k * self.n_experts / self.n_router
+                    + self.n_shared_experts)
             total += 2 * d                       # norms
             active += 2 * d
         if self.is_encdec:                        # encoder stack + cross attn
@@ -155,8 +182,12 @@ class ModelConfig:
             return q + kv + o
         if mixer == "mla":
             qk_head = self.qk_nope_head_dim + self.qk_rope_head_dim
-            p = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * qk_head
+            if self.q_lora_rank:              # low-rank q with its norm
+                p = self.q_lora_rank * (d + 1 + self.n_heads * qk_head)
+            else:
+                p = d * self.n_heads * qk_head
             p += d * (self.kv_lora_rank + self.qk_rope_head_dim)
+            p += self.kv_lora_rank            # kv norm
             p += self.kv_lora_rank * self.n_heads * (
                 self.qk_nope_head_dim + self.v_head_dim)
             p += self.n_heads * self.v_head_dim * d

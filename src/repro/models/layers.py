@@ -8,6 +8,7 @@ can shard them as first-class inputs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -155,10 +156,14 @@ def mla_init(key, cfg: ModelConfig, dtype) -> Dict:
     h = cfg.n_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     ks = jax.random.split(key, 7)
+    if cfg.q_lora_rank:                 # low-rank query with its own norm
+        q = {"wdq": dense_init(ks[0], d, cfg.q_lora_rank, dtype=dtype),
+             "q_norm": rmsnorm_init(cfg.q_lora_rank),
+             "wuq": dense_init(ks[1], cfg.q_lora_rank, h * qk, dtype=dtype)}
+    else:                               # a direct query projection
+        q = {"wq": dense_init(ks[0], d, h * qk, dtype=dtype)}
     return {
-        "wdq": dense_init(ks[0], d, cfg.q_lora_rank, dtype=dtype),
-        "q_norm": rmsnorm_init(cfg.q_lora_rank),
-        "wuq": dense_init(ks[1], cfg.q_lora_rank, h * qk, dtype=dtype),
+        **q,
         "wdkv": dense_init(ks[2], d, cfg.kv_lora_rank, dtype=dtype),
         "kv_norm": rmsnorm_init(cfg.kv_lora_rank),
         "wkr": dense_init(ks[3], d, cfg.qk_rope_head_dim, dtype=dtype),
@@ -175,14 +180,17 @@ def _mla_qkv(p, x, cfg: ModelConfig, positions):
     """Shared q / (compressed kv) computation. Returns q, c_kv, k_rope."""
     b, s, _ = x.shape
     h = cfg.n_heads
-    cq = rmsnorm(x @ p["wdq"], p["q_norm"], cfg.norm_eps)
-    q = (cq @ p["wuq"]).reshape(
-        b, s, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
-    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    c_kv = rmsnorm(x @ p["wdkv"], p["kv_norm"], cfg.norm_eps)   # (B,S,r_kv)
-    k_rope = apply_rope((x @ p["wkr"])[:, :, None, :], positions,
-                        cfg.rope_theta)                          # (B,S,1,dr)
+    with obs.scope("attn_proj"):
+        if cfg.q_lora_rank:
+            q = rmsnorm(x @ p["wdq"], p["q_norm"], cfg.norm_eps) @ p["wuq"]
+        else:
+            q = x @ p["wq"]
+        q = q.reshape(b, s, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+        q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+        c_kv = rmsnorm(x @ p["wdkv"], p["kv_norm"], cfg.norm_eps)  # (B,S,r)
+        k_rope = apply_rope((x @ p["wkr"])[:, :, None, :], positions,
+                            cfg.rope_theta)                      # (B,S,1,dr)
     return q_nope, q_rope, c_kv, k_rope
 
 
@@ -190,23 +198,44 @@ def mla_apply(p, x, cfg: ModelConfig, positions) -> jax.Array:
     b, s, _ = x.shape
     h = cfg.n_heads
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, positions)
-    kv = (c_kv @ p["wukv"]).reshape(
-        b, s, h, cfg.qk_nope_head_dim + cfg.v_head_dim)
-    k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
-    k_rope_h = jnp.broadcast_to(
-        k_rope, (b, s, h, cfg.qk_rope_head_dim))
-    q = jnp.concatenate([q_nope, q_rope], -1)
-    k = jnp.concatenate([k_nope, k_rope_h], -1)
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    # v head dim != qk head dim -> pad v to qk width for the shared kernel
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - cfg.v_head_dim)))
-    out = ops.flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        vp.transpose(0, 2, 1, 3), causal=True, window=None, scale=scale,
-        impl=cfg.attn_impl, q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
-    out = out.transpose(0, 2, 1, 3)[..., :cfg.v_head_dim]
-    return out.reshape(b, s, h * cfg.v_head_dim) @ p["wo"]
+    with obs.scope("attn_proj"):
+        kv = (c_kv @ p["wukv"]).reshape(
+            b, s, h, cfg.qk_nope_head_dim + cfg.v_head_dim)
+        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+        k_rope_h = jnp.broadcast_to(
+            k_rope, (b, s, h, cfg.qk_rope_head_dim))
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        k = jnp.concatenate([k_nope, k_rope_h], -1)
+        # v head dim != qk head dim -> pad v to qk width for the shared
+        # kernel
+        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - cfg.v_head_dim)))
+        qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, vp))
+    out = _causal_by_query_chunk(qt, kt, vt, cfg, scale=qk ** -0.5)
+    with obs.scope("attn_proj"):
+        out = out.transpose(0, 2, 1, 3)[..., :cfg.v_head_dim]
+        return out.reshape(b, s, h * cfg.v_head_dim) @ p["wo"]
+
+
+def _causal_by_query_chunk(q, k, v, cfg: ModelConfig, scale: float):
+    """Causal attention, one call per chunk of ``cfg.q_chunk`` queries over
+    the keys up to its end, each recomputed in the backward pass.  Without
+    that the backward of a remat layer holds every score block of the
+    sequence at once: 4.6 GB in float32 for 16 heads of two sequences of
+    8192 (AOT compile for a v5e).  This way one chunk's blocks are live at a
+    time, for one more forward pass of the attention."""
+    s = q.shape[2]
+    c = min(cfg.q_chunk, s)
+    outs = []
+    for lo in range(0, s, c):
+        attend = functools.partial(
+            ops.flash_attention, causal=True, window=None, offset=lo,
+            scale=scale, impl=cfg.attn_impl, q_chunk=c, kv_chunk=cfg.kv_chunk)
+        if s > c:
+            attend = jax.checkpoint(attend)
+        outs.append(attend(q[:, :, lo:lo + c], k[:, :, :lo + c],
+                           v[:, :, :lo + c]))
+    return jnp.concatenate(outs, axis=2) if len(outs) > 1 else outs[0]
 
 
 def mla_make_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):

@@ -37,6 +37,14 @@ def batch_axes() -> Tuple[str, ...]:
     return _STATE["batch_axes"]
 
 
+def axis_size(name: str) -> int:
+    """Devices along the registered mesh's axis ``name`` (1 without one)."""
+    mesh = _STATE["mesh"]
+    if mesh is None or name not in mesh.shape:
+        return 1
+    return int(mesh.shape[name])
+
+
 def constrain(x, *spec):
     """with_sharding_constraint(x, P(*spec)) if a mesh is registered.
 

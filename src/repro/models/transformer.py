@@ -7,7 +7,7 @@ stacked parameters, keeping the compiled HLO size independent of depth.
 
 Entry points:
   init(key, cfg)                      -> params
-  forward(params, x, cfg, positions)  -> (hidden, aux_loss)
+  forward(params, x, cfg, positions)  -> (hidden, aux)
   lm_loss(params, batch, cfg)         -> (loss, metrics)
   init_cache(cfg, batch, max_len)     -> decode cache
   decode_step(params, cache, tok, pos, cfg) -> (logits, cache)
@@ -62,8 +62,10 @@ def block_init(key, spec, cfg: ModelConfig, dtype) -> Params:
 
 
 def block_apply(bp, x, spec, cfg: ModelConfig, positions):
+    """-> (x, aux): ``aux["loss"]`` the block's balance loss, and for a MoE
+    block ``aux["moe"]`` its counters (``moe.moe_apply``)."""
     mixer, ffn = spec
-    aux = jnp.zeros((), jnp.float32)
+    aux = {"loss": jnp.zeros((), jnp.float32)}
     h = L.rmsnorm(x, bp["ln1"], cfg.norm_eps)
     if mixer == "attn":
         mx = L.attn_apply(bp["mixer"], h, cfg, positions)
@@ -79,7 +81,7 @@ def block_apply(bp, x, spec, cfg: ModelConfig, positions):
     if ffn is not None:
         h2 = L.rmsnorm(x, bp["ln2"], cfg.norm_eps)
         if ffn == "moe":
-            y, aux = M.moe_apply(bp["ffn"], h2, cfg)
+            y, aux["loss"], aux["moe"] = M.moe_apply(bp["ffn"], h2, cfg)
         else:
             y = L.mlp_apply(bp["ffn"], h2)
         x = x + y
@@ -118,7 +120,7 @@ def block_decode(bp, x, cache, spec, cfg: ModelConfig, pos):
     if ffn is not None:
         h2 = L.rmsnorm(x, bp["ln2"], cfg.norm_eps)
         if ffn == "moe":
-            y, _ = M.moe_apply(bp["ffn"], h2[:, None, :], cfg)
+            y, _, _ = M.moe_apply(bp["ffn"], h2[:, None, :], cfg)
             y = y[:, 0]
         else:
             y = L.mlp_apply(bp["ffn"], h2)
@@ -163,33 +165,41 @@ def init(key, cfg: ModelConfig) -> Params:
     return params
 
 
-def forward(params, x, cfg: ModelConfig, positions) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) embedded inputs -> (hidden (B,S,D), aux_loss)."""
+def forward(params, x, cfg: ModelConfig, positions) -> Tuple[jax.Array, Dict]:
+    """x: (B, S, D) embedded inputs -> (hidden (B,S,D), aux): ``aux["loss"]``
+    the balance losses summed, ``aux["moe"]`` the counters of each MoE
+    position of the stack (``stack/pos<i>``), stacked over its layers."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.first_k_dense:
         spec = (cfg.period[0][0], "mlp")
         for bp in params["prefix"]:
             x, a = block_apply(bp, x, spec, cfg, positions)
-            aux += a
+            aux += a["loss"]
 
     def period_body(carry, xs):
         x, aux = carry
+        moe = {}
         for i, spec in enumerate(cfg.period):
             x, a = block_apply(xs[f"pos{i}"], x, spec, cfg, positions)
-            aux += a
-        return (x, aux), None
+            aux += a["loss"]
+            if "moe" in a:
+                moe[f"stack/pos{i}"] = a["moe"]
+        return (x, aux), moe
 
     if cfg.remat:
         period_body = jax.checkpoint(
             period_body, policy=jax.checkpoint_policies.nothing_saveable)
     if cfg.scan_layers:
-        (x, aux), _ = jax.lax.scan(period_body, (x, aux), params["stack"])
+        (x, aux), moe = jax.lax.scan(period_body, (x, aux), params["stack"])
     else:
+        per = []
         for j in range(cfg.n_periods):
             sl = jax.tree.map(lambda a: a[j], params["stack"])
-            (x, aux), _ = period_body((x, aux), sl)
+            (x, aux), m = period_body((x, aux), sl)
+            per.append(m)
+        moe = jax.tree.map(lambda *a: jnp.stack(a), *per)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return x, aux
+    return x, {"loss": aux, "moe": moe}
 
 
 def logits_fn(params, h, cfg: ModelConfig) -> jax.Array:
@@ -234,6 +244,8 @@ def lm_loss(params, batch, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
     labels = batch["labels"]
     mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
     h, aux = forward(params, x, cfg, positions)
+    moe = dict(aux["moe"])
+    aux = aux["loss"]
     tot, cnt = _chunked_ce(params, h, labels, mask, cfg)
     loss = tot / jnp.maximum(cnt, 1.0)
     metrics = {"ce": loss, "aux": aux, "tokens": cnt}
@@ -244,14 +256,18 @@ def lm_loss(params, batch, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
         e_in = L.rmsnorm(embed_tokens(params, labels[:, :-1], cfg),
                          mp["norm_e"], cfg.norm_eps)
         x2 = jnp.concatenate([h_in, e_in], axis=-1) @ mp["proj"]
-        x2, _ = block_apply(mp["block"], x2, cfg.period[0], cfg,
-                            positions[:-1])
+        x2, a2 = block_apply(mp["block"], x2, cfg.period[0], cfg,
+                             positions[:-1])
+        if "moe" in a2:
+            moe["mtp/block"] = a2["moe"]
         x2 = L.rmsnorm(x2, mp["final_norm"], cfg.norm_eps)
         tot2, cnt2 = _chunked_ce(params, x2, labels[:, 1:], mask[:, 1:], cfg)
         mtp_loss = tot2 / jnp.maximum(cnt2, 1.0)
         loss = loss + cfg.mtp_weight * mtp_loss
         metrics["mtp"] = mtp_loss
     loss = loss + aux
+    if moe:
+        metrics.update(M.counters(moe, cfg))
     return loss, metrics
 
 

@@ -28,7 +28,11 @@ SCOPES = (
     "norm",         # every RMSNorm
     "attn_proj",    # the attention's q/k/v projections with RoPE, and output
     "attention",    # the attention kernel: forward, recompute, backward
-    "mlp",          # the SwiGLU MLP
+    "mlp",          # the SwiGLU MLP, and a MoE layer's shared experts
+    "router",       # a MoE router: scores, selection, gates, balance loss
+    "dispatch",     # token copies sorted to their experts, gathered, and
+                    # scatter-added back weighted (the combine)
+    "experts",      # the held experts' grouped matmuls
     "head_loss",    # logits and cross-entropy
     "optimizer",    # gradient clipping and the optimizer's update
 )

@@ -101,12 +101,9 @@ def test_decode_matches_teacher_forcing_dense():
 def test_decode_matches_teacher_forcing_hybrid():
     """Same equivalence for the jamba hybrid (mamba + attn + moe).
 
-    MoE capacity depends on batch size (T=B*S), so routing can differ
-    between the full pass and step-wise decode when experts overflow; the
-    smoke config uses ample capacity to keep them identical."""
+    On one device the MoE layer is dropless, so a token's routing does not
+    depend on how many tokens share its step."""
     cfg = get("jamba_1_5_large_398b", smoke=True)
-    import dataclasses
-    cfg = dataclasses.replace(cfg, capacity_factor=8.0)
     api = model_api(cfg)
     params = api.init(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(2), (B, 8), 0, cfg.vocab)

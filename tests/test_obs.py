@@ -67,9 +67,8 @@ def _op_name(line):
     return m.group(1) if m else None
 
 
-def test_every_matmul_of_the_train_step_has_one_innermost_scope(
-        compiled_text):
-    comps = _instructions(compiled_text)
+def _matmuls(text):
+    comps = _instructions(text)
     matmuls = []
     for lines in comps.values():
         for line in lines:
@@ -77,6 +76,12 @@ def test_every_matmul_of_the_train_step_has_one_innermost_scope(
             if " dot(" in line or (callee and _holds_dot(comps,
                                                          callee.group(1))):
                 matmuls.append(line)
+    return matmuls
+
+
+def test_every_matmul_of_the_train_step_has_one_innermost_scope(
+        compiled_text):
+    matmuls = _matmuls(compiled_text)
     assert len(matmuls) >= 20
     for line in matmuls:
         name = _op_name(line)
@@ -91,9 +96,46 @@ def test_every_scope_reaches_the_forward_and_the_backward_ops(compiled_text):
         scope = name and obs.innermost(name)
         if scope:
             (backward if "transpose(" in name else forward).add(scope)
-    model = set(obs.SCOPES) - {"optimizer"}
+    # a dense model opens every scope but the mixture of experts' own
+    model = set(obs.SCOPES) - {"optimizer"} - MOE_SCOPES
     assert model <= forward and model <= backward
     assert "optimizer" in forward          # after the gradient, not in it
+
+
+MOE_SCOPES = {"router", "dispatch", "experts"}
+
+
+@pytest.fixture(scope="module")
+def moe_compiled_text():
+    cfg = get("moonlight_16b_a3b", smoke=True)
+    api = model_api(cfg)
+    params = api.init(jax.random.PRNGKey(0), cfg)
+    step = jax.jit(make_train_step(cfg, OPT))
+    return step.lower(params, OPT.init(params),
+                      _batch(2, 32)).compile().as_text()
+
+
+def test_every_matmul_of_a_moe_train_step_has_one_innermost_scope(
+        moe_compiled_text):
+    matmuls = _matmuls(moe_compiled_text)
+    assert len(matmuls) >= 20
+    for line in matmuls:
+        name = _op_name(line)
+        assert name is not None, line[:200]
+        assert obs.innermost(name) in obs.SCOPES, name
+
+
+@pytest.mark.parametrize("name", sorted(MOE_SCOPES) + ["attn_proj", "mlp",
+                                                       "attention"])
+def test_moe_scopes_reach_the_compiled_step(moe_compiled_text, name):
+    # latent attention, the routed experts and the shared experts (mlp)
+    # of a mixture-of-experts train step, forward and backward
+    found = {"forward": False, "backward": False}
+    for line in moe_compiled_text.splitlines():
+        op = _op_name(line)
+        if op and obs.innermost(op) == name:
+            found["backward" if "transpose(" in op else "forward"] = True
+    assert found == {"forward": True, "backward": True}
 
 
 @pytest.mark.parametrize("op_name, want", [
@@ -102,6 +144,10 @@ def test_every_scope_reaches_the_forward_and_the_backward_ops(compiled_text):
     ("jit(train_step)/transpose(jvp(head_loss))/dot_general", "head_loss"),
     ("jit(f)/mlp/attention/bhgqd,bhkd->bhgqk/dot_general:", "attention"),
     ("jit(train_step)/optimizer/jit(norm)/sqrt", "optimizer"),
+    ("jit(train_step)/jvp()/while/body/closed_call/router/top_k", "router"),
+    ("jit(train_step)/transpose(jvp(experts))/ragged_dot_general",
+     "experts"),
+    ("jit(train_step)/jvp(dispatch)/scatter-add", "dispatch"),
     ("jit(norm)/sqrt", None),
     ("jit(train_step)/jvp()/while/body/squeeze", None),
     ("", None),

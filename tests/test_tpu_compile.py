@@ -109,3 +109,42 @@ def test_mamba_scan_compiles_at_jamba_slice(one_chip):
              [((1, t, d_in), BF16), ((1, t, d_in), BF16), ((d_in, n), F32),
               ((1, t, n), BF16), ((1, t, n), BF16), ((d_in,), F32)],
              one_chip)
+
+
+def test_moonlight_train_step_fits_one_chip(one_chip):
+    # the moonlight-16b-a3b.train-s8192 cell's whole train step at its real
+    # shapes: 568.5M parameters with AdamW state, batch 2 x 8192, blockwise
+    # attention at head dim 192 and the dropless grouped matmuls.  5.69e9
+    # bytes of arguments and 8.67e9 of temp (16.85e9 before latent
+    # attention recomputed its query chunks)
+    import json
+    from pathlib import Path
+
+    from repro.launch.steps import make_train_step
+    from repro.models import model_api
+    from repro.models.config import ModelConfig
+    from repro.optim.optimizers import adamw
+
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    m = json.loads((chip / "configs" / "moonlight-16b-a3b.json").read_text())
+    tr = json.loads((chip / "traffic" / "train-s8192.json").read_text())
+    m = dict(m["model"], period=tuple(map(tuple, m["model"]["period"])),
+             router_bias_speed=tr["bias_speed"],
+             router_aux_weight=tr["balance_alpha"])
+    cfg = ModelConfig(**m)
+    opt = adamw(3e-4)
+    api = model_api(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: api.init(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(opt.init, params)
+    batch = {k: jax.ShapeDtypeStruct((tr["batch"], tr["seq"]), I32,
+                                     sharding=one_chip)
+             for k in ("inputs", "labels")}
+    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1)) \
+        .lower(on_chip(params), on_chip(state), batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9, mem
