@@ -3,15 +3,31 @@
 Every op has three interchangeable implementations:
 
 * ``impl="ref"``    — the naive oracle from :mod:`.ref` (tests, tiny shapes);
-* ``impl="jnp"``    — memory-bounded blockwise jnp (default off-TPU; this is
-  what the multi-pod dry-run lowers, so compile-time memory analysis reflects
-  flash-style tiling rather than materialised S^2 score matrices);
+* ``impl="jnp"``    — the default: memory-bounded blockwise jnp, which for
+  attention becomes a Pallas flash kernel with a backward where the call
+  allows it and the program is lowered for a TPU (see below);
 * ``impl="pallas"`` — the Pallas TPU kernels (interpret mode off-TPU).
+
+Attention under ``impl="jnp"`` is one algorithm, online-softmax attention,
+with two implementations chosen by what the call shows.  A call with no
+sliding window, both sequence lengths multiples of 128 and whole groups of
+query heads per kv head runs, when lowered for a TPU, in JAX's shipped
+splash attention kernel, whose forward, dq and dkv kernels keep every score
+block in VMEM.  Every other call, and every call lowered for another
+platform (the CPU tests, the multi-device dry-run), runs the blockwise jnp
+path.  The choice is ``lax.platform_dependent``'s, made when the program is
+lowered, unless the caller passes a mesh of TPU devices: the program is
+then built for a TPU and the call takes the kernel at trace time.
+:func:`repro.obs.counts` counts both at trace time (``attention.kernel``,
+``attention.blockwise``).
 
 The blockwise jnp path implements *causal block skipping*: for causal and
 sliding-window attention, key/value blocks that are entirely masked for a
 query chunk are statically sliced away, so the compiled FLOPs reflect the
-~2x triangle saving (visible in ``cost_analysis`` — see EXPERIMENTS.md §Perf).
+~2x triangle saving (visible in ``cost_analysis``).  The kernel skips fully
+masked blocks too, but a Mosaic custom call carries no FLOP count: lowered
+for a TPU, ``cost_analysis`` (and the dry-run that reads it) no longer sees
+the attention's FLOPs.
 """
 from __future__ import annotations
 
@@ -21,6 +37,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as _splash,
+    splash_attention_mask as _splash_mask,
+)
 
 from repro import obs
 from . import ref as _ref
@@ -129,15 +149,104 @@ def _flash_jnp(q, k, v, causal, window, offset, scale, q_chunk, kv_chunk,
     return out[:, :, :sq0] if pq else out
 
 
+# ---------------------------------------------------------------------------
+# The flash kernel with a backward (splash attention)
+# ---------------------------------------------------------------------------
+
+def _splash_block_sizes(sq: int, skv: int) -> _splash.BlockSizes:
+    """One block size for every kernel: the largest of 1024/512/256/128
+    that divides both lengths.  The dkv kernel computes its tile 512 keys at
+    a time: beside the tile it holds float32 dk and dv accumulators and
+    double-buffered q, k, v and do blocks, and at 1024 x 1024 it overflows
+    the 16 MiB of scoped VMEM at head dim 192 (moonlight's chunks over 6144
+    and 7168 keys, compiled for a v5e)."""
+    blk = next(b for b in (1024, 512, 256, 128)
+               if sq % b == 0 and skv % b == 0)
+    return _splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk,
+        block_kv_dkv_compute=min(blk, 512),
+        block_q_dq=blk, block_kv_dq=blk)
+
+
+@functools.lru_cache(maxsize=64)
+def _splash_kernel(heads: int, sq: int, skv: int, causal: bool, offset: int,
+                   interpret: bool):
+    """The splash kernel for one mask, built once: its mask info (which
+    blocks are full, partial or empty) is numpy work on the host, and the
+    layer scan, a remat retrace and an MLA layer's query chunks ask for the
+    same masks again."""
+    mask = (_splash_mask.CausalMask((sq, skv), offset=offset) if causal
+            else _splash_mask.FullMask((sq, skv)))
+    # concrete mask info even when first asked for inside a trace, so the
+    # cached kernel holds no tracer
+    with jax.ensure_compile_time_eval():
+        return _splash.make_splash_mha(
+            _splash_mask.MultiHeadMask([mask] * heads),
+            block_sizes=_splash_block_sizes(sq, skv), head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "offset", "scale",
+                                             "interpret"))
+def _splash_attend(q, k, v, *, causal, offset, scale, interpret):
+    """The splash kernel over one device's q (B, Hq, Sq, D), k (B, Hkv, Skv,
+    D) and v (B, Hkv, Skv, Dv).  The batch folds into the heads: query head
+    b*Hq + i reads kv head (b*Hq + i) // G = b*Hkv + i // G, as GQA asks."""
+    b, h, sq, _ = q.shape
+    kernel = _splash_kernel(b * h, sq, k.shape[2], causal, offset, interpret)
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    fold = lambda x: x.reshape(-1, *x.shape[2:])
+    return kernel(fold(q), fold(k), fold(v)).reshape(b, h, sq, v.shape[-1])
+
+
+def _flash_splash(q, k, v, causal, offset, scale, mesh=None, batch_axes=(),
+                  interpret=False):
+    """Attention in the splash kernel: q (B, Hq, Sq, D), k (B, Hkv, Skv, D),
+    v (B, Hkv, Skv, Dv), Hq a multiple of Hkv, both lengths multiples of
+    128.  Operands stay in their dtype; the kernel accumulates and keeps its
+    softmax statistics in float32.  ``mesh``: the devices the program runs
+    on, with the batch split over ``batch_axes``."""
+    attend = functools.partial(_splash_attend, causal=causal, offset=offset,
+                               scale=scale, interpret=interpret)
+    if mesh is None or mesh.size == 1:
+        return attend(q, k, v)
+    # XLA cannot partition a Mosaic kernel: on a mesh of several devices
+    # each takes its share of the batch (and of the heads, split over the
+    # "model" axis where the batch does not use it and they divide evenly)
+    # in a call of its own
+    ba = tuple(batch_axes) or None
+    if ba and q.shape[0] % math.prod(mesh.shape[a] for a in ba):
+        ba = None
+    m = mesh.shape.get("model", 1)
+    heads = ("model" if "model" not in (ba or ()) and q.shape[1] % m == 0
+             and k.shape[1] % m == 0 else None)
+    spec = jax.sharding.PartitionSpec(ba, heads, None, None)
+    return jax.shard_map(attend, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _splash_eligible(q, k, window) -> bool:
+    """Whether the kernel can take a call, from its shapes and mask."""
+    return (window is None and q.shape[2] % 128 == 0
+            and k.shape[2] % 128 == 0 and q.shape[1] % k.shape[1] == 0)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: Optional[int] = None,
                     offset: int = 0, scale: Optional[float] = None,
                     impl: str = "jnp", q_chunk: int = 512,
-                    kv_chunk: int = 1024) -> jax.Array:
+                    kv_chunk: int = 1024, mesh=None,
+                    batch_axes: tuple = ()) -> jax.Array:
     """Blockwise attention with GQA, causal masking and sliding windows.
 
     q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D); returns (B, Hq, Sq, D).
     ``offset``: absolute position of q[0] relative to kv[0] (prefill chunks).
+    Under ``impl="jnp"`` a call the splash kernel can take runs in it when
+    lowered for a TPU (module docstring); ``q_chunk``/``kv_chunk`` size the
+    blockwise path only.  ``mesh``: the mesh the program is sharded over,
+    with the batch split over ``batch_axes``; the kernel runs per shard in
+    it, and a mesh of TPU devices settles the platform at trace time.
     """
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     with obs.scope("attention"):
@@ -148,8 +257,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             return flash_attention_pallas(q, k, v, causal=causal,
                                           window=window, offset=offset,
                                           scale=scale)
-        return _flash_jnp(q, k, v, causal, window, offset, scale, q_chunk,
-                          kv_chunk)
+        if not _splash_eligible(q, k, window):
+            obs.count("attention.blockwise")
+            return _flash_jnp(q, k, v, causal, window, offset, scale,
+                              q_chunk, kv_chunk)
+        obs.count("attention.kernel")
+        kernel = functools.partial(_flash_splash, causal=causal,
+                                   offset=offset, scale=scale, mesh=mesh,
+                                   batch_axes=batch_axes)
+        if mesh is not None and all(d.platform == "tpu"
+                                    for d in mesh.devices.flat):
+            # The program is built for these TPUs, so only the kernel is
+            # traced.  Differentiating platform_dependent's conditional
+            # traces the kernel's Pallas bodies again in each of its passes:
+            # moonlight's whole step then traced in 16.06 s, against 10.39 s
+            # with the blockwise path alone (one v5e host).
+            return kernel(q, k, v)
+        # Without TPU devices to go by, both branches are traced and
+        # differentiated, each as a jitted function of the call's statics:
+        # JAX caches the trace and derivatives of a jitted function for
+        # the calls that repeat its shapes.
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=kernel,
+            default=functools.partial(
+                _blockwise_jit, causal=causal, offset=offset, scale=scale,
+                q_chunk=q_chunk, kv_chunk=kv_chunk))
+
+
+_blockwise_jit = jax.jit(
+    functools.partial(_flash_jnp, window=None),
+    static_argnames=("causal", "offset", "scale", "q_chunk", "kv_chunk"))
 
 
 def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -207,6 +344,7 @@ def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           q_chunk, kv_chunk, kv_valid_lo=lo)
 
     with obs.scope("attention"):
+        obs.count("attention.blockwise")
         return f(q, k, v)
 
 

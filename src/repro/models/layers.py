@@ -64,6 +64,17 @@ def attn_init(key, cfg: ModelConfig, dtype, cross: bool = False) -> Dict:
     }
 
 
+def _attention_shards(cfg: ModelConfig) -> Dict[str, Any]:
+    """The registered mesh and the axes the batch splits over, for
+    :func:`ops.flash_attention`, whose kernel runs once per shard."""
+    from . import partitioning as part
+    mesh = part._STATE["mesh"]
+    if mesh is None:
+        return {}
+    return {"mesh": mesh, "batch_axes": (tuple(mesh.axis_names) if cfg.pure_dp
+                                         else part.batch_axes())}
+
+
 def attn_apply(p, x, cfg: ModelConfig, positions, causal=True,
                use_rope=True) -> jax.Array:
     """Full-sequence attention. x: (B, S, D) -> (B, S, D)."""
@@ -88,7 +99,8 @@ def attn_apply(p, x, cfg: ModelConfig, positions, causal=True,
     else:
         out = ops.flash_attention(
             qt, kt, vt, causal=causal, window=cfg.window,
-            impl=cfg.attn_impl, q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+            impl=cfg.attn_impl, q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+            **_attention_shards(cfg))
     with obs.scope("attn_proj"):
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
         return out @ p["wo"]
@@ -103,7 +115,7 @@ def cross_attn_apply(p, x, kv_cache, cfg: ModelConfig) -> jax.Array:
     k, v = kv_cache
     out = ops.flash_attention(q, k, v, causal=False, window=None,
                               impl=cfg.attn_impl, q_chunk=cfg.q_chunk,
-                              kv_chunk=cfg.kv_chunk)
+                              kv_chunk=cfg.kv_chunk, **_attention_shards(cfg))
     return out.transpose(0, 2, 1, 3).reshape(b, s, h * hd) @ p["wo"]
 
 
@@ -230,7 +242,8 @@ def _causal_by_query_chunk(q, k, v, cfg: ModelConfig, scale: float):
     for lo in range(0, s, c):
         attend = functools.partial(
             ops.flash_attention, causal=True, window=None, offset=lo,
-            scale=scale, impl=cfg.attn_impl, q_chunk=c, kv_chunk=cfg.kv_chunk)
+            scale=scale, impl=cfg.attn_impl, q_chunk=c, kv_chunk=cfg.kv_chunk,
+            **_attention_shards(cfg))
         if s > c:
             attend = jax.checkpoint(attend)
         outs.append(attend(q[:, :, lo:lo + c], k[:, :, :lo + c],

@@ -8,6 +8,10 @@ each compiled instruction carries the innermost scope in its ``op_name``
 ``transpose(jvp(<scope>))``), and a profiler trace carries it on as the op's
 ``tf_op``.  They change no instruction.
 
+Counts are kept at trace time: :func:`count` adds one each time traced code
+passes it (``attention.kernel``, ``attention.blockwise``), so a jitted
+function counts once per trace, not once per call.
+
 The compile log keeps, for each top-level jitted function, the seconds it
 spent tracing, lowering and compiling (or loading from JAX's persistent
 cache), and whether that cache hit.  It listens to ``jax.monitoring`` from
@@ -43,6 +47,22 @@ def scope(name: str):
     if name not in SCOPES:
         raise ValueError(f"unknown scope {name!r}; SCOPES is {SCOPES}")
     return jax.named_scope(name)
+
+
+_COUNTS: Dict[str, int] = {}
+_COUNTS_LOCK = threading.Lock()
+
+
+def count(name: str) -> None:
+    """Add one to the trace-time count ``name``."""
+    with _COUNTS_LOCK:
+        _COUNTS[name] = _COUNTS.get(name, 0) + 1
+
+
+def counts() -> Dict[str, int]:
+    """Every trace-time count so far, by name."""
+    with _COUNTS_LOCK:
+        return dict(_COUNTS)
 
 
 # a path component: a name, bare or wrapped in transforms (``jvp(mlp)``)

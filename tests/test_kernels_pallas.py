@@ -11,7 +11,8 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ref
+from repro import obs
+from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
@@ -65,6 +66,111 @@ def test_flash_pallas_hypothesis_sweep(b, group, s, causal, window):
     exp = ref.attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=3e-5,
                                rtol=3e-5)
+
+
+def _grads(fn, q, k, v, ct):
+    """Gradients of <fn(q, k, v), ct> with respect to q, k and v."""
+    loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+# (b, hq, hkv, sq, skv, d, causal, offset): the training calls the splash
+# kernel takes, at tiny sizes
+SPLASH_CALLS = {
+    "gqa_causal": (1, 6, 2, 256, 256, 64, True, 0),          # smollm-like
+    "causal_offset_chunk": (1, 2, 2, 128, 384, 64, True, 256),  # MLA chunk
+    "non_causal": (2, 4, 2, 128, 256, 64, False, 0),
+    "head_dim_192": (1, 2, 2, 128, 256, 192, True, 128),     # MLA widths
+}
+
+
+@pytest.mark.parametrize("call", sorted(SPLASH_CALLS))
+def test_splash_kernel_matches_ref(call):
+    """The kernel path, interpreted, against the oracle: outputs and the
+    q, k and v gradients, bf16 operands."""
+    b, hq, hkv, sq, skv, d, causal, offset = SPLASH_CALLS[call]
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = rand(ks[0], (b, hq, sq, d), jnp.bfloat16)
+    k = rand(ks[1], (b, hkv, skv, d), jnp.bfloat16)
+    v = rand(ks[2], (b, hkv, skv, d), jnp.bfloat16)
+    ct = rand(ks[3], (b, hq, sq, d))
+    scale = d ** -0.5
+
+    def kernel(q, k, v):
+        return ops._flash_splash(q, k, v, causal, offset, scale,
+                                 interpret=True)
+
+    def oracle(q, k, v):
+        return ref.attention_ref(q, k, v, causal, None, offset, scale)
+
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v), np.float32),
+                               np.asarray(oracle(q, k, v), np.float32),
+                               atol=2e-2, rtol=2e-2)
+    for g, e in zip(_grads(kernel, q, k, v, ct), _grads(oracle, q, k, v, ct)):
+        g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
+        np.testing.assert_allclose(g, e, rtol=2e-2,
+                                   atol=2e-2 * float(np.abs(e).max()))
+
+
+def _cp_call(q, k, v):
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    return ops.cp_flash_attention(q, k, v, mesh, causal=True)
+
+
+# (q shape, kv shape, call): which path each caller's call takes
+ATTENTION_PATHS = {
+    "train_gqa": ((2, 6, 256, 64), (2, 2, 256, 64),
+                  lambda q, k, v: ops.flash_attention(q, k, v), "kernel"),
+    "mla_chunk": ((1, 4, 128, 192), (1, 4, 384, 192),
+                  lambda q, k, v: ops.flash_attention(q, k, v, offset=256),
+                  "kernel"),
+    "encoder_1500": ((1, 4, 1500, 64), (1, 4, 1500, 64),
+                     lambda q, k, v: ops.flash_attention(q, k, v,
+                                                         causal=False),
+                     "blockwise"),
+    "prefill_ragged": ((1, 4, 200, 64), (1, 4, 200, 64),
+                       lambda q, k, v: ops.flash_attention(q, k, v),
+                       "blockwise"),
+    "sliding_window": ((1, 4, 256, 64), (1, 4, 256, 64),
+                       lambda q, k, v: ops.flash_attention(q, k, v,
+                                                           window=128),
+                       "blockwise"),
+    "kv_valid_lo_ring": ((1, 4, 256, 64), (1, 4, 256, 64), _cp_call,
+                         "blockwise"),
+}
+
+
+@pytest.mark.parametrize("call", sorted(ATTENTION_PATHS))
+def test_attention_call_takes_its_path(call):
+    """The trace-time counts say which path a call takes: a window, a
+    length that is not a multiple of 128 or a context-parallel ring bound
+    stays blockwise; the training calls can take the kernel."""
+    qs, ks, fn, want = ATTENTION_PATHS[call]
+    other = {"kernel": "blockwise", "blockwise": "kernel"}[want]
+    before = obs.counts()
+    jax.eval_shape(fn, *(jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                         for s in (qs, ks, ks)))
+    after = obs.counts()
+    delta = {n: after.get(f"attention.{n}", 0)
+             - before.get(f"attention.{n}", 0) for n in (want, other)}
+    assert delta == {want: 1, other: 0}
+
+
+@pytest.mark.parametrize("with_cpu_mesh", [False, True])
+def test_kernel_choice_waits_for_lowering_without_tpu_devices(with_cpu_mesh):
+    """With no mesh, or a mesh of CPU devices, an eligible call stages both
+    paths behind lax.platform_dependent: the platform is chosen when the
+    program is lowered, so a CPU lowering gets the blockwise path."""
+    from repro.launch.mesh import make_mesh
+    kw = ({"mesh": make_mesh((1, 1), ("data", "model")),
+           "batch_axes": ("data",)} if with_cpu_mesh else {})
+    s = jax.ShapeDtypeStruct((1, 2, 256, 64), jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: jnp.sum(ops.flash_attention(
+        q, k, v, **kw).astype(jnp.float32)), argnums=(0, 1, 2))
+    traced = jax.jit(grad).trace(s, s, s)
+    assert "platform_index" in str(traced.jaxpr)
+    assert "tpu_custom_call" not in traced.lower().as_text()
 
 
 @pytest.mark.parametrize("b,hq,hkv,s,d", [

@@ -138,6 +138,76 @@ def test_moe_scopes_reach_the_compiled_step(moe_compiled_text, name):
     assert found == {"forward": True, "backward": True}
 
 
+def _custom_call_names(module_text):
+    """{kernel name: full op name} of the Mosaic calls in a lowered
+    module's text (``as_text(debug_info=True)``).  A call's location names
+    it within its function; the names of the calls that reach that function
+    from ``main`` come first."""
+    loc = {}
+    for ref, body in re.findall(r"^(#loc\d+) = loc\((.*)\)$", module_text,
+                                re.M):
+        m = re.match(r'"([^"]*)"', body)
+        loc[ref] = m.group(1) if m else ""
+    caller, kernels, func = {}, [], None
+    for line in module_text.splitlines():
+        head = re.match(r"\s*func\.func (?:public |private )?@([\w.$-]+)\(",
+                        line)
+        if head:
+            func = head.group(1)
+            continue
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        name = loc.get(at.group(1), "") if at else ""
+        call = re.search(r"\bcall @([\w.$-]+)\(", line)
+        if call:
+            caller[call.group(1)] = (func, name)
+        elif "@tpu_custom_call" in line:
+            kernels.append((func, name))
+
+    def path(f):
+        if f not in caller:
+            return ""
+        parent, name = caller[f]
+        return f"{path(parent)}/{name}"
+
+    return {name.split("/")[0]: path(f) + "/" + name for f, name in kernels}
+
+
+@pytest.fixture(scope="module")
+def attention_layer_grad():
+    """One attention layer's gradient, recomputed in the backward as the
+    model's remat runs it, traced once for either platform."""
+    from repro.models import layers
+    params = jax.eval_shape(
+        lambda: layers.attn_init(jax.random.PRNGKey(0), CFG, jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((2, 128, CFG.d_model), jnp.bfloat16)
+    layer = jax.checkpoint(
+        lambda p, x: layers.attn_apply(p, x, CFG, jnp.arange(128)),
+        policy=jax.checkpoint_policies.nothing_saveable)
+    grad = jax.value_and_grad(
+        lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)),
+        argnums=(0, 1))
+    return jax.jit(grad).trace(params, x)
+
+
+def test_attention_kernels_lowered_for_a_tpu_keep_the_scope(
+        attention_layer_grad):
+    # attention_ms.train reads the kernels' time through this scope
+    text = attention_layer_grad.lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = _custom_call_names(text)
+    for phase in ("fwd", "dq", "dkv"):
+        assert any(f"_{phase}_" in k for k in names), names
+    for kernel, name in names.items():
+        assert obs.innermost(name) == "attention", name
+        if "_fwd_" not in kernel:           # the backward's kernels
+            assert "transpose(" in name, name
+
+
+def test_attention_lowered_for_the_cpu_has_no_kernel(attention_layer_grad):
+    text = attention_layer_grad.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in text
+
+
 @pytest.mark.parametrize("op_name, want", [
     ("jit(train_step)/jvp()/while/body/closed_call/attn_proj/dot_general",
      "attn_proj"),

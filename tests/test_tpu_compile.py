@@ -25,14 +25,18 @@ from repro.kernels.rmsnorm import rmsnorm_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:              # no libtpu, or it is held elsewhere
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -87,6 +91,115 @@ def test_jnp_attention_backward_bytes_at_smollm_cell(one_chip):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *args).compile()
     assert compiled.cost_analysis()["bytes accessed"] < 13e9
+
+
+def _attention_grad(attend, shapes, sharding):
+    """The gradient of one checkpointed attention call, as a remat layer
+    runs it (forward, recompute, backward), compiled."""
+    attn = jax.checkpoint(attend,
+                          policy=jax.checkpoint_policies.nothing_saveable)
+
+    def loss(q, k, v, ct):
+        return jnp.sum(attn(q, k, v).astype(F32) * ct)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+
+
+# (q, kv, offset): smollm-360m's layer in the train cell; moonlight's
+# 1024-query chunks at head dim 192 (v padded to it): the last, over 8192
+# keys, and the one over 7168, whose dkv kernel overflowed the scoped VMEM
+# at 1024 x 1024 tiles
+TRAIN_ATTENTION = {
+    "smollm_360m": ((8, 15, 2048, 64), (8, 5, 2048, 64), 0),
+    "moonlight_last_chunk": ((2, 16, 1024, 192), (2, 16, 8192, 192), 7168),
+    "moonlight_chunk_7168": ((2, 16, 1024, 192), (2, 16, 7168, 192), 6144),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(TRAIN_ATTENTION))
+def test_attention_kernel_backward_compiles_in_less_temp(one_chip, cell):
+    # the train cells' attention takes the splash kernel on a TPU: its
+    # forward, dq and dkv kernels keep the score blocks in VMEM, so its
+    # temp is below the blockwise path's at the same call
+    qs, kvs, off = TRAIN_ATTENTION[cell]
+    shapes = [(qs, BF16), (kvs, BF16), (kvs, BF16), (qs, F32)]
+    kernel = _attention_grad(
+        lambda q, k, v: ops.flash_attention(q, k, v, offset=off), shapes,
+        one_chip)
+    assert kernel.as_text().count("tpu_custom_call") >= 3
+    blockwise = _attention_grad(
+        lambda q, k, v: ops._flash_jnp(q, k, v, True, None, off,
+                                       qs[-1] ** -0.5, 1024, 1024),
+        shapes, one_chip)
+    assert "tpu_custom_call" not in blockwise.as_text()
+    assert (kernel.memory_analysis().temp_size_in_bytes
+            < blockwise.memory_analysis().temp_size_in_bytes)
+
+
+# (mesh shape, axes the batch splits over): data parallel on four chips;
+# batch over "data" and heads over "model"; pure data parallelism over both
+MESHES = [((4, 1), ("data",)), ((2, 2), ("data",)),
+          ((2, 2), ("data", "model"))]
+
+
+@pytest.mark.parametrize("mesh_shape", MESHES)
+def test_attention_kernel_compiles_on_a_mesh_of_four(topo, mesh_shape):
+    # XLA cannot partition a Mosaic kernel: on a mesh of several chips,
+    # passed in by the caller, each takes its share of the batch (and
+    # heads) in a call of its own, or lowering fails
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    shape, batch_axes = mesh_shape
+    mesh = Mesh(np.array(topo.devices).reshape(shape),
+                ("data", "model"))
+    q, kv = (8, 16, 1024, 64), (8, 4, 1024, 64)
+    compiled = _attention_grad(
+        functools.partial(ops.flash_attention, mesh=mesh,
+                          batch_axes=batch_axes),
+        [(q, BF16), (kv, BF16), (kv, BF16), (q, F32)],
+        NamedSharding(mesh, P(batch_axes)))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("pure_dp", [False, True])
+def test_attention_layer_gives_the_kernel_its_mesh(topo, pure_dp):
+    # the model's layer passes the registered mesh and its batch axes on,
+    # so the training step on several chips lowers the kernel per shard,
+    # and the kernel alone is traced
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get
+    from repro.models import layers
+    from repro.models import partitioning as part
+    cfg = dataclasses.replace(get("smollm_360m", smoke=True), n_heads=6,
+                              n_kv_heads=2, pure_dp=pure_dp)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    params = jax.eval_shape(
+        lambda: layers.attn_init(jax.random.PRNGKey(0), cfg, BF16))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, P())), params)
+    x = jax.ShapeDtypeStruct((4, 256, cfg.d_model), BF16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def loss(p, x):
+        out = layers.attn_apply(p, x, cfg, jnp.arange(256))
+        return jnp.sum(out.astype(F32))
+
+    with part.use_mesh(mesh, ("data",)):
+        traced = jax.jit(jax.grad(loss)).trace(params, x)
+    # a mesh of TPUs settles the platform when the step is traced: no
+    # platform-dependent conditional, no blockwise branch
+    assert "platform_index" not in str(traced.jaxpr)
+    compiled = traced.lower().compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
 def test_decode_attention_compiles(one_chip):
