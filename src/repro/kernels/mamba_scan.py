@@ -6,9 +6,9 @@ discretisation (exp(dt*A)), state update and C-projection — the HBM traffic
 is exactly one read of u/dt/B/C and one write of y (the jnp fallback
 materialises (B, T, d, N) discretised terms or re-reads per chunk).
 
-TPU adaptation note (DESIGN.md §2): the CUDA kernel in the Mamba paper tiles
-over threadblocks with warp shuffles for the chunk-carry; on TPU the carry
-lives in VMEM scratch across sequential time steps of one grid cell instead.
+TPU adaptation note: the CUDA kernel in the Mamba paper tiles over
+threadblocks with warp shuffles for the chunk-carry; on TPU the carry lives
+in VMEM scratch across sequential time steps of one grid cell instead.
 """
 from __future__ import annotations
 

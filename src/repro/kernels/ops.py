@@ -1,25 +1,23 @@
 """Public kernel entry points used by the model zoo.
 
-Every op has three interchangeable implementations:
+Each op has one implementation, except attention, which chooses between
+two by what the call shows: its shapes, its mask and the platform.
+Nothing chooses by name.  RMSNorm and one-token decode attention run the
+plain jnp forms of :mod:`.ref`, which are also the tests' oracles; the
+Mamba scan is a ``lax.scan``.  The Pallas kernels in this package's other
+modules are on no path (the package docstring).
 
-* ``impl="ref"``    — the naive oracle from :mod:`.ref` (tests, tiny shapes);
-* ``impl="jnp"``    — the default: memory-bounded blockwise jnp, which for
-  attention becomes a Pallas flash kernel with a backward where the call
-  allows it and the program is lowered for a TPU (see below);
-* ``impl="pallas"`` — the Pallas TPU kernels (interpret mode off-TPU).
-
-Attention under ``impl="jnp"`` is one algorithm, online-softmax attention,
-with two implementations chosen by what the call shows.  A call with no
-sliding window, both sequence lengths multiples of 128 and whole groups of
-query heads per kv head runs, when lowered for a TPU, in JAX's shipped
-splash attention kernel, whose forward, dq and dkv kernels keep every score
-block in VMEM.  Every other call, and every call lowered for another
-platform (the CPU tests, the multi-device dry-run), runs the blockwise jnp
-path.  The choice is ``lax.platform_dependent``'s, made when the program is
-lowered, unless the caller passes a mesh of TPU devices: the program is
-then built for a TPU and the call takes the kernel at trace time.
-:func:`repro.obs.counts` counts both at trace time (``attention.kernel``,
-``attention.blockwise``).
+Attention is one algorithm, online-softmax attention, with two
+implementations.  A call with no sliding window, both sequence lengths
+multiples of 128 and whole groups of query heads per kv head runs, when
+lowered for a TPU, in JAX's shipped splash attention kernel, whose
+forward, dq and dkv kernels keep every score block in VMEM.  Every other
+call, and every call lowered for another platform (the CPU tests, the
+multi-device dry-run), runs the blockwise jnp path.  The choice is
+``lax.platform_dependent``'s, made when the program is lowered, unless the
+caller passes a mesh of TPU devices: the program is then built for a TPU
+and the call takes the kernel at trace time.  :func:`repro.obs.counts`
+counts both at trace time (``attention.kernel``, ``attention.blockwise``).
 
 The blockwise jnp path implements *causal block skipping*: for causal and
 sliding-window attention, key/value blocks that are entirely masked for a
@@ -48,11 +46,7 @@ from . import ref as _ref
 NEG_INF = -1e30
 
 
-def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
-            impl: str = "jnp") -> jax.Array:
-    if impl == "pallas":
-        from .rmsnorm import rmsnorm_pallas
-        return rmsnorm_pallas(x, scale, eps)
+def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return _ref.rmsnorm_ref(x, scale, eps)
 
 
@@ -235,28 +229,20 @@ def _splash_eligible(q, k, window) -> bool:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: Optional[int] = None,
                     offset: int = 0, scale: Optional[float] = None,
-                    impl: str = "jnp", q_chunk: int = 512,
-                    kv_chunk: int = 1024, mesh=None,
+                    q_chunk: int = 512, kv_chunk: int = 1024, mesh=None,
                     batch_axes: tuple = ()) -> jax.Array:
     """Blockwise attention with GQA, causal masking and sliding windows.
 
     q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D); returns (B, Hq, Sq, D).
     ``offset``: absolute position of q[0] relative to kv[0] (prefill chunks).
-    Under ``impl="jnp"`` a call the splash kernel can take runs in it when
-    lowered for a TPU (module docstring); ``q_chunk``/``kv_chunk`` size the
-    blockwise path only.  ``mesh``: the mesh the program is sharded over,
-    with the batch split over ``batch_axes``; the kernel runs per shard in
-    it, and a mesh of TPU devices settles the platform at trace time.
+    A call the splash kernel can take runs in it when lowered for a TPU
+    (module docstring); ``q_chunk``/``kv_chunk`` size the blockwise path
+    only.  ``mesh``: the mesh the program is sharded over, with the batch
+    split over ``batch_axes``; the kernel runs per shard in it, and a mesh
+    of TPU devices settles the platform at trace time.
     """
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     with obs.scope("attention"):
-        if impl == "ref":
-            return _ref.attention_ref(q, k, v, causal, window, offset, scale)
-        if impl == "pallas":
-            from .flash_attention import flash_attention_pallas
-            return flash_attention_pallas(q, k, v, causal=causal,
-                                          window=window, offset=offset,
-                                          scale=scale)
         if not _splash_eligible(q, k, window):
             obs.count("attention.blockwise")
             return _flash_jnp(q, k, v, causal, window, offset, scale,
@@ -303,8 +289,8 @@ def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     in a *relative* frame (q row 0 sits at offset r*L), so causal/window
     block skipping stays static while a traced validity bound masks the
     ring edges.  Per-shard work is uniform (striped-attention-style balance);
-    the collectives are the small K/V blocks instead of activation psums
-    (EXPERIMENTS.md §Perf H2).
+    the collectives are the small K/V blocks, not psums of whole
+    activations.
     """
     from jax.sharding import PartitionSpec as P
     n = mesh.shape[axis]
@@ -351,13 +337,8 @@ def cp_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      length: Optional[jax.Array] = None,
                      window: Optional[int] = None,
-                     scale: Optional[float] = None,
-                     impl: str = "jnp") -> jax.Array:
+                     scale: Optional[float] = None) -> jax.Array:
     """One-token attention vs. a KV cache. q: (B, Hq, D); k/v: (B, Hkv, S, D)."""
-    if impl == "pallas":
-        from .decode_attention import decode_attention_pallas
-        return decode_attention_pallas(q, k, v, length=length, window=window,
-                                       scale=scale)
     return _ref.decode_attention_ref(q, k, v, length, window, scale)
 
 
@@ -366,19 +347,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 
 def mamba_scan(u: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-               C: jax.Array, D: jax.Array, h0: Optional[jax.Array] = None,
-               impl: str = "jnp"):
+               C: jax.Array, D: jax.Array, h0: Optional[jax.Array] = None):
     """Selective SSM scan. Shapes as :func:`repro.kernels.ref.mamba_scan_ref`.
 
-    The jnp path is a `lax.scan` over time — O(T) sequential, O(1) state
-    memory; the Pallas path tiles d_inner into VMEM blocks.
+    A `lax.scan` over time — O(T) sequential, O(1) state memory.
     Returns (y (Bt,T,d_in), h_T (Bt,d_in,N)).
     """
-    if impl == "ref":
-        return _ref.mamba_scan_ref(u, dt, A, B, C, D, h0)
-    if impl == "pallas":
-        from .mamba_scan import mamba_scan_pallas
-        return mamba_scan_pallas(u, dt, A, B, C, D, h0)
     bt, t, d_in = u.shape
     n = A.shape[1]
     h_init = jnp.zeros((bt, d_in, n), jnp.float32) if h0 is None else \

@@ -82,20 +82,29 @@ class ModelConfig:
     # --- runtime ---
     force_fsdp: bool = False          # ZeRO-3 sharding even for small models
     pure_dp: bool = False             # no TP: replicate params, batch over
-                                      # every mesh axis (right-sizing for
-                                      # sub-1B models; EXPERIMENTS.md H1)
+                                      # every mesh axis (for sub-1B models,
+                                      # whose TP shards are too small)
     seq_shard: bool = False           # context parallelism: shard the
                                       # sequence dim over 'model' (long
-                                      # prefill; EXPERIMENTS.md H2)
+                                      # prefill)
     dtype: str = "bfloat16"
     remat: bool = True
     scan_layers: bool = True
-    attn_impl: str = "jnp"            # jnp | pallas | ref
+    attn_impl: str = "jnp"            # read by nothing (kernels are chosen
+                                      # from each call); kept for the
+                                      # benchmark's cell files, which set
+                                      # it, and goes when they drop the key
+                                      # (PERF.md, Open question 26)
     q_chunk: int = 1024
     kv_chunk: int = 1024
     mamba_chunk: int = 64
 
     def __post_init__(self):
+        if self.attn_impl != "jnp":
+            raise ValueError(
+                f"{self.name}: attn_impl {self.attn_impl!r}: the kernel is "
+                "chosen from the call (shapes, mask, platform), not by name; "
+                "only 'jnp' is accepted")
         assert self.n_layers % len(self.period) == 0, \
             f"{self.name}: n_layers {self.n_layers} not divisible by " \
             f"period {len(self.period)}"

@@ -168,7 +168,7 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
         q = (h @ bp["cross"]["wq"]).reshape(
             x.shape[0], cfg.n_heads, cfg.hd)
         from repro.kernels import ops
-        ca = ops.decode_attention(q, ck, cv, impl=cfg.attn_impl)
+        ca = ops.decode_attention(q, ck, cv)
         x = x + ca.reshape(x.shape[0], -1) @ bp["cross"]["wo"]
         h = L.rmsnorm(x, bp["ln2"], cfg.norm_eps)
         x = x + L.mlp_apply(bp["mlp"], h)

@@ -68,7 +68,7 @@ def _attention_shards(cfg: ModelConfig) -> Dict[str, Any]:
     """The registered mesh and the axes the batch splits over, for
     :func:`ops.flash_attention`, whose kernel runs once per shard."""
     from . import partitioning as part
-    mesh = part._STATE["mesh"]
+    mesh = part.mesh()
     if mesh is None:
         return {}
     return {"mesh": mesh, "batch_axes": (tuple(mesh.axis_names) if cfg.pure_dp
@@ -89,17 +89,17 @@ def attn_apply(p, x, cfg: ModelConfig, positions, causal=True,
             k = apply_rope(k, positions, cfg.rope_theta)
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
     from . import partitioning as part
-    mesh = part._STATE["mesh"]
+    mesh = part.mesh()
     if cfg.seq_shard and causal and mesh is not None and \
             s % mesh.shape["model"] == 0:
-        # context parallelism (H2): S sharded over 'model'; ring-gather K/V
+        # context parallelism: S sharded over 'model'; ring-gather K/V
         out = ops.cp_flash_attention(
             qt, kt, vt, mesh, axis="model", causal=True, window=cfg.window,
             q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
     else:
         out = ops.flash_attention(
             qt, kt, vt, causal=causal, window=cfg.window,
-            impl=cfg.attn_impl, q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+            q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
             **_attention_shards(cfg))
     with obs.scope("attn_proj"):
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
@@ -114,8 +114,8 @@ def cross_attn_apply(p, x, kv_cache, cfg: ModelConfig) -> jax.Array:
     q = (x @ p["wq"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
     k, v = kv_cache
     out = ops.flash_attention(q, k, v, causal=False, window=None,
-                              impl=cfg.attn_impl, q_chunk=cfg.q_chunk,
-                              kv_chunk=cfg.kv_chunk, **_attention_shards(cfg))
+                              q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                              **_attention_shards(cfg))
     return out.transpose(0, 2, 1, 3).reshape(b, s, h * hd) @ p["wo"]
 
 
@@ -149,10 +149,8 @@ def attn_decode(p, x, cache, pos, cfg: ModelConfig, use_rope=True):
         (0, 0, slot, 0))
     length = jnp.minimum(pos + 1, c)
     out = ops.decode_attention(
-        q[:, 0].transpose(0, 2, 1).reshape(b, h, hd)
-        if False else q.reshape(b, h, hd),
-        ck, cv, length=jnp.broadcast_to(length, (b,)).astype(jnp.int32),
-        impl=cfg.attn_impl)
+        q.reshape(b, h, hd), ck, cv,
+        length=jnp.broadcast_to(length, (b,)).astype(jnp.int32))
     # NOTE on ring buffers: with a window ring buffer every slot < length is
     # valid (all within the last `window` positions), so no extra masking is
     # needed beyond `length`.
@@ -242,7 +240,7 @@ def _causal_by_query_chunk(q, k, v, cfg: ModelConfig, scale: float):
     for lo in range(0, s, c):
         attend = functools.partial(
             ops.flash_attention, causal=True, window=None, offset=lo,
-            scale=scale, impl=cfg.attn_impl, q_chunk=c, kv_chunk=cfg.kv_chunk,
+            scale=scale, q_chunk=c, kv_chunk=cfg.kv_chunk,
             **_attention_shards(cfg))
         if s > c:
             attend = jax.checkpoint(attend)
@@ -264,8 +262,8 @@ def mla_decode(p, x, cache, pos, cfg: ModelConfig, absorbed: bool = True):
     ``absorbed=True`` uses the weight-absorption trick: queries are mapped
     into the latent space (q' = q_nope @ W_ukv^k) and attention runs directly
     over c_kv — no per-step decompression of the whole cache.  With
-    ``absorbed=False`` the cache is decompressed each step (baseline; see
-    EXPERIMENTS.md §Perf for the measured difference).
+    ``absorbed=False`` the cache is decompressed each step (the naive form
+    the absorbed one is tested against).
     """
     b, d = x.shape
     h = cfg.n_heads

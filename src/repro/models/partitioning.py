@@ -33,6 +33,11 @@ def use_mesh(mesh: Optional[Mesh], batch_axes: Tuple[str, ...] = ("data",)):
         set_mesh(*prev)
 
 
+def mesh() -> Optional[Mesh]:
+    """The registered mesh (None without one)."""
+    return _STATE["mesh"]
+
+
 def batch_axes() -> Tuple[str, ...]:
     return _STATE["batch_axes"]
 

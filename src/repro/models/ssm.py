@@ -77,9 +77,7 @@ def _mamba_core(p, xc, z, cfg, h0=None):
     dt_r, Bm, Cm = jnp.split(proj, [r, r + n], axis=-1)
     dt = jax.nn.softplus(dt_r @ p["dt_proj"] + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
-    y, h_last = ops.mamba_scan(xc, dt, A, Bm, Cm, p["D"], h0=h0,
-                               impl=cfg.attn_impl if cfg.attn_impl == "pallas"
-                               else "jnp")
+    y, h_last = ops.mamba_scan(xc, dt, A, Bm, Cm, p["D"], h0=h0)
     return y * jax.nn.silu(z), h_last
 
 

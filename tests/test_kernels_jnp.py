@@ -29,8 +29,7 @@ def test_flash_jnp_matches_ref(b, hq, hkv, sq, skv, d, causal, window, dtype):
     k = rand(ks[1], (b, hkv, skv, d), dtype)
     v = rand(ks[2], (b, hkv, skv, d), dtype)
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              offset=offset, impl="jnp", q_chunk=32,
-                              kv_chunk=64)
+                              offset=offset, q_chunk=32, kv_chunk=64)
     exp = ref.attention_ref(q, k, v, causal=causal, window=window,
                             offset=offset)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -65,7 +64,7 @@ def test_flash_jnp_grad_matches_ref(b, hq, hkv, sq, skv, d, causal, window,
     v = rand(ks[2], (b, hkv, skv, d), dtype)
     ct = rand(ks[3], (b, hq, sq, d))
     got = _grads(lambda q, k, v: ops.flash_attention(
-        q, k, v, causal=causal, window=window, offset=offset, impl="jnp",
+        q, k, v, causal=causal, window=window, offset=offset,
         q_chunk=32, kv_chunk=64), q, k, v, ct)
     exp = _grads(lambda q, k, v: ref.attention_ref(
         q, k, v, causal=causal, window=window, offset=offset), q, k, v, ct)
@@ -123,7 +122,7 @@ def test_flash_jnp_block_skipping_reduces_flops():
 
     def cost(causal):
         fn = lambda q, k, v: ops.flash_attention(
-            q, k, v, causal=causal, impl="jnp", q_chunk=64, kv_chunk=64)
+            q, k, v, causal=causal, q_chunk=64, kv_chunk=64)
         return jax.jit(fn).lower(q, k, v).compile().cost_analysis()["flops"]
 
     assert cost(True) < 0.65 * cost(False)
@@ -151,7 +150,7 @@ def test_mamba_scan_matches_ref(dtype):
     B = rand(ks[3], (bt, t, n), dtype)
     C = rand(ks[4], (bt, t, n), dtype)
     D = jnp.ones((d_in,))
-    y, h = ops.mamba_scan(u, dt, A, B, C, D, impl="jnp")
+    y, h = ops.mamba_scan(u, dt, A, B, C, D)
     y_ref, h_ref = ref.mamba_scan_ref(u, dt, A, B, C, D)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(y, np.float32),

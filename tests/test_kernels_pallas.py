@@ -1,7 +1,8 @@
 """Pallas kernels (interpret=True on CPU) vs pure-jnp oracles.
 
-Shape/dtype sweeps per the brief; hypothesis drives randomised GQA/window
-combinations for the attention kernels.
+Shape/dtype sweeps of the kernels; which path each attention call takes;
+hypothesis drives randomised GQA/window combinations through the attention
+entry point.
 """
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,6 @@ from hypothesis import given, settings, strategies as st
 from repro import obs
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
@@ -23,76 +23,35 @@ def rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, jnp.float32).astype(dtype)
 
 
-@pytest.mark.parametrize("b,hq,hkv,sq,skv,d", [
-    (1, 2, 2, 128, 128, 64),
-    (2, 4, 1, 64, 128, 32),          # MQA, q shorter than kv
-    (1, 8, 2, 128, 128, 128),        # GQA 4:1
-])
-@pytest.mark.parametrize("causal,window", [
-    (True, None), (False, None), (True, 48),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_pallas_matches_ref(b, hq, hkv, sq, skv, d, causal, window,
-                                  dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    offset = skv - sq
-    q = rand(ks[0], (b, hq, sq, d), dtype)
-    k = rand(ks[1], (b, hkv, skv, d), dtype)
-    v = rand(ks[2], (b, hkv, skv, d), dtype)
-    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 offset=offset, q_blk=32, kv_blk=32)
-    exp = ref.attention_ref(q, k, v, causal=causal, window=window,
-                            offset=offset)
-    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32), atol=tol,
-                               rtol=tol)
-
-
-@settings(max_examples=10, deadline=None)
-@given(st.sampled_from([1, 2]), st.sampled_from([1, 2, 4]),
-       st.sampled_from([64, 128]), st.booleans(),
-       st.sampled_from([None, 32, 64]))
-def test_flash_pallas_hypothesis_sweep(b, group, s, causal, window):
-    hkv = 2
-    hq = hkv * group
-    d = 32
-    ks = jax.random.split(jax.random.PRNGKey(42), 3)
-    q = rand(ks[0], (b, hq, s, d))
-    k = rand(ks[1], (b, hkv, s, d))
-    v = rand(ks[2], (b, hkv, s, d))
-    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 q_blk=32, kv_blk=32)
-    exp = ref.attention_ref(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=3e-5,
-                               rtol=3e-5)
-
-
 def _grads(fn, q, k, v, ct):
     """Gradients of <fn(q, k, v), ct> with respect to q, k and v."""
     loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct)
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-# (b, hq, hkv, sq, skv, d, causal, offset): the training calls the splash
-# kernel takes, at tiny sizes
+# (b, hq, hkv, sq, skv, d, causal, offset): the calls the splash kernel
+# takes, at tiny sizes
 SPLASH_CALLS = {
     "gqa_causal": (1, 6, 2, 256, 256, 64, True, 0),          # smollm-like
     "causal_offset_chunk": (1, 2, 2, 128, 384, 64, True, 256),  # MLA chunk
     "non_causal": (2, 4, 2, 128, 256, 64, False, 0),
     "head_dim_192": (1, 2, 2, 128, 256, 192, True, 128),     # MLA widths
+    "mqa_q_shorter": (2, 4, 1, 128, 256, 64, True, 128),
+    "gqa_4to1_d128_causal": (1, 8, 2, 128, 128, 128, True, 0),
+    "gqa_4to1_d128_non_causal": (1, 8, 2, 128, 128, 128, False, 0),
 }
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("call", sorted(SPLASH_CALLS))
-def test_splash_kernel_matches_ref(call):
+def test_splash_kernel_matches_ref(call, dtype):
     """The kernel path, interpreted, against the oracle: outputs and the
-    q, k and v gradients, bf16 operands."""
+    q, k and v gradients, in either operand dtype."""
     b, hq, hkv, sq, skv, d, causal, offset = SPLASH_CALLS[call]
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    q = rand(ks[0], (b, hq, sq, d), jnp.bfloat16)
-    k = rand(ks[1], (b, hkv, skv, d), jnp.bfloat16)
-    v = rand(ks[2], (b, hkv, skv, d), jnp.bfloat16)
+    q = rand(ks[0], (b, hq, sq, d), dtype)
+    k = rand(ks[1], (b, hkv, skv, d), dtype)
+    v = rand(ks[2], (b, hkv, skv, d), dtype)
     ct = rand(ks[3], (b, hq, sq, d))
     scale = d ** -0.5
 
@@ -138,6 +97,18 @@ ATTENTION_PATHS = {
                        "blockwise"),
     "kv_valid_lo_ring": ((1, 4, 256, 64), (1, 4, 256, 64), _cp_call,
                          "blockwise"),
+    "mqa_d128": ((2, 4, 128, 128), (2, 1, 256, 128),
+                 lambda q, k, v: ops.flash_attention(q, k, v, offset=128),
+                 "kernel"),
+    "gqa_d128": ((1, 8, 128, 128), (1, 2, 128, 128),
+                 lambda q, k, v: ops.flash_attention(q, k, v), "kernel"),
+    "q_64_kv_128": ((2, 4, 64, 32), (2, 1, 128, 32),
+                    lambda q, k, v: ops.flash_attention(q, k, v, offset=64),
+                    "blockwise"),
+    "window_with_offset": ((1, 4, 128, 64), (1, 4, 256, 64),
+                           lambda q, k, v: ops.flash_attention(
+                               q, k, v, window=48, offset=128),
+                           "blockwise"),
 }
 
 
@@ -155,6 +126,27 @@ def test_attention_call_takes_its_path(call):
     delta = {n: after.get(f"attention.{n}", 0)
              - before.get(f"attention.{n}", 0) for n in (want, other)}
     assert delta == {want: 1, other: 0}
+
+
+@settings(max_examples=10, deadline=None)
+@given(st.sampled_from([1, 2]), st.sampled_from([1, 2, 4]),
+       st.sampled_from([64, 128]), st.booleans(),
+       st.sampled_from([None, 32, 64]))
+def test_flash_attention_hypothesis_sweep(b, group, s, causal, window):
+    """The entry point against the oracle over GQA groups, lengths, causal
+    masks and windows, whichever path each call takes."""
+    hkv = 2
+    hq = hkv * group
+    d = 32
+    ks = jax.random.split(jax.random.PRNGKey(42), 3)
+    q = rand(ks[0], (b, hq, s, d))
+    k = rand(ks[1], (b, hkv, s, d))
+    v = rand(ks[2], (b, hkv, s, d))
+    out = ops.flash_attention(q, k, v, causal=causal, window=window,
+                              q_chunk=32, kv_chunk=32)
+    exp = ref.attention_ref(q, k, v, causal=causal, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=3e-5,
+                               rtol=3e-5)
 
 
 @pytest.mark.parametrize("with_cpu_mesh", [False, True])

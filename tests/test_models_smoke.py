@@ -146,3 +146,15 @@ def test_param_count_formula_tracks_actual():
         actual = param_count(params)
         est, _ = cfg.param_count()
         assert abs(actual - est) / actual < 0.35, (arch, actual, est)
+
+
+@pytest.mark.parametrize("attn_impl", ["pallas", "ref"])
+def test_config_rejects_a_kernel_chosen_by_name(attn_impl):
+    """The kernels are chosen from each call; a config that names one is
+    refused rather than silently ignored."""
+    import dataclasses
+    cfg = get("smollm_360m", smoke=True)
+    with pytest.raises(ValueError, match="chosen from the call"):
+        dataclasses.replace(cfg, attn_impl=attn_impl)
+    with pytest.raises(ValueError, match="chosen from the call"):
+        ModelConfig(**{**dataclasses.asdict(cfg), "attn_impl": attn_impl})

@@ -19,7 +19,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
@@ -65,20 +64,23 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
 def test_flash_attention_compiles_at_smollm_widths(one_chip):
-    # smollm-360m: 15 query heads over 5 kv heads, head_dim 64
-    _compile(lambda q, k, v: flash_attention_pallas(q, k, v, interpret=False),
+    # smollm-360m's prefill: 15 query heads over 5 kv heads, head_dim 64;
+    # the entry point, lowered for the chip, takes the splash kernel
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v),
              [((1, 15, 2048, 64), BF16), ((1, 5, 2048, 64), BF16),
               ((1, 5, 2048, 64), BF16)], one_chip)
 
 
 def test_jnp_attention_backward_bytes_at_smollm_cell(one_chip):
-    # one smollm-360m layer of the train cell: batch 8 x 2048, chunks of
-    # 1024, forward, remat recompute and backward as the model's checkpoint
-    # runs them.  Held constant in the backward, the running max leaves no
-    # max-location mask per score block: 11.7e9 bytes (15.6e9 without).
+    # one smollm-360m layer of the train cell on the blockwise path: batch
+    # 8 x 2048, chunks of 1024, forward, remat recompute and backward as the
+    # model's checkpoint runs them.  Held constant in the backward, the
+    # running max leaves no max-location mask per score block: 11.7e9 bytes
+    # (15.6e9 without).  The entry point would take the splash kernel here
+    # (1.5e9 bytes), so the blockwise path is called directly.
     attn = jax.checkpoint(
-        lambda q, k, v: ops.flash_attention(q, k, v, impl="jnp",
-                                            q_chunk=1024, kv_chunk=1024),
+        lambda q, k, v: ops._flash_jnp(q, k, v, True, None, 0, 64 ** -0.5,
+                                       1024, 1024),
         policy=jax.checkpoint_policies.nothing_saveable)
 
     def loss(q, k, v, ct):
