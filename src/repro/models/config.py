@@ -50,8 +50,8 @@ class ModelConfig:
     seq_aux: bool = False             # sequence-wise balance loss (DeepSeek-
                                       # V3), else switch-style over the batch
     moe_dispatch_groups: int = 1      # grouped local dispatch (H3, a2a-shaped)
-    moe_combine_dtype: str = "float32"  # scatter-add accumulator (H3 iter-3:
-                                        # bfloat16 halves combine traffic)
+    moe_combine_dtype: str = "float32"  # the combine's accumulator
+                                        # (bfloat16 halves its traffic)
 
     # --- MLA (DeepSeek) ---
     mla: bool = False

@@ -21,8 +21,10 @@ Two dispatches, chosen by what the layer observes:
 * dropless, where the experts are not sharded over a mesh axis (one chip):
   the token copies routed to held experts are sorted by expert, each held
   expert runs a grouped matmul (:func:`grouped_matmul`) over exactly its
-  rows, and the results are scatter-added, weighted, to their tokens.  No
-  copy is dropped.
+  rows, and the results are gathered back to token order and summed,
+  weighted, over each token's k copies.  Both directions move rows by
+  gathers through the sort's permutation and its inverse, forward and
+  backward, so no scatter-add runs into the tokens.  No copy is dropped.
 * capacity, where the experts are sharded over ``model`` (the multi-device
   dry-run): each expert takes a window of ``capacity_factor`` times its
   share of the copies, gathered so that GSPMD lowers the dispatch to
@@ -199,35 +201,105 @@ def grouped_matmul(a, w, sizes):
                                       default=jax.lax.ragged_dot)
 
 
+# The copies of the dropless dispatch are moved by gathers only, both ways
+# and in both passes.  ``slot`` gives each sorted copy's place in slot-major
+# order (copy j of token t at ``j * T + t``) and ``inv`` is its inverse, so
+# that a token's k copies in slot-major order are k slices of T rows: every
+# sum over a token's copies is a sum of slices, which XLA fuses with what
+# consumes it, where a sum over a (T, k, D) axis, or a scatter-add into the
+# tokens, goes through HBM (or runs serially, for the scatter) on a TPU.
+
+@jax.custom_vjp
+def _copies(x, slot, inv, held):
+    """Each sorted copy's token row, ``x[slot % T]``, or 0 for a copy not
+    held, read from a zero row (a mask on the gather's result would be a
+    pass of its own).  Its gradient gathers the copies back to slot-major
+    order and sums each token's k slices over the held copies."""
+    t = x.shape[0]
+    rows = jnp.where(held.reshape(-1)[slot], slot % t, t)
+    return jnp.concatenate([x, jnp.zeros_like(x[:1])])[rows]
+
+
+def _copies_fwd(x, slot, inv, held):
+    return _copies(x, slot, inv, held), (inv, held)
+
+
+def _copies_bwd(res, g):
+    inv, held = res
+    back, (k, t) = g[inv], held.shape
+    total = sum(jnp.where(held[j][:, None], back[j * t:(j + 1) * t], 0)
+                .astype(jnp.float32) for j in range(k))
+    return total.astype(g.dtype), None, None, None
+
+
+_copies.defvjp(_copies_fwd, _copies_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, held, slot, inv):
+    """``y[t] = sum_j w[j, t] * ys[inv[j * T + t]]`` over the copies
+    ``held[j, t]``, in ``w``'s dtype: the sorted copies' results back to
+    their tokens, weighted.  The row of a copy not held is not read, so rows
+    that no held expert wrote may hold anything.  The gradient stays in
+    sorted order: each copy's token cotangent is gathered through ``slot``
+    from the T token rows, and only the weights' gradient, one number a
+    copy, goes back through ``inv``."""
+    return _combine_fwd(ys, w, held, slot, inv)[0]
+
+
+def _combine_fwd(ys, w, held, slot, inv):
+    back, (k, t) = ys[inv], w.shape
+
+    def copy(j):                    # the j-th copy of every token, or 0
+        return jnp.where(held[j][:, None], back[j * t:(j + 1) * t], 0)
+
+    y = sum(copy(j).astype(w.dtype) * w[j][:, None] for j in range(k))
+    return y, (ys, w, held, slot, inv)
+
+
+def _combine_bwd(res, g):
+    ys, w, held, slot, inv = res
+    here = held.reshape(-1)[slot][:, None]
+    gs = g[slot % g.shape[0]]           # each sorted copy's token cotangent
+    g_ys = jnp.where(here, gs * w.reshape(-1)[slot][:, None], 0)
+    g_w = jnp.sum(jnp.where(here, gs * ys, 0), -1)[inv].reshape(w.shape)
+    return g_ys.astype(ys.dtype), g_w, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _dropless(p, xf, idx, gates, cfg: ModelConfig, acc_dt):
     """Every copy routed to a held expert, computed by a grouped matmul over
     the true group sizes."""
+    obs.count("moe.dropless")
     t, d = xf.shape
     e, k = cfg.n_experts, cfg.top_k
     with obs.scope("dispatch"):
         flat = idx.reshape(-1)                                  # (T*k,)
         expert = jnp.where(flat < e, flat, e)       # absent experts: last
         order = jnp.argsort(expert, stable=True)
+        slot = (order % k) * t + order // k
+        inv = jnp.argsort(slot)     # a sort: cheaper than a set on a TPU
         sizes = jnp.bincount(expert, length=e + 1)[:e].astype(jnp.int32)
-        tok = order // k
         here = (expert < e)[order][:, None]   # the rows of the held groups
-        wgt = jnp.where(here[:, 0], gates.reshape(-1)[order], 0.0)
-        xs = jnp.where(here, xf[tok], 0)                        # (T*k, D)
+        held = (idx < e).T                    # (k, T): copy j of token t
+        xs = _copies(xf, slot, inv, held)                       # (T*k, D)
 
     # The TPU's grouped matmuls leave the rows past the last group
-    # unwritten, in their result and in their input's gradient alike: every
-    # result is masked before it is used, and so is the input, whose
-    # gradient would otherwise carry those rows to the tokens.
+    # unwritten, in their result and in their input's gradient alike: the
+    # gate and up results are masked before they are used, the copies send
+    # no gradient from a row not held to the tokens, and the combine reads
+    # no row of a copy not held and sends those rows a gradient of 0.
     def grouped(a, w):
         return jnp.where(here, grouped_matmul(a, w, sizes), 0)
 
     with obs.scope("experts"):
         ex = p["experts"]
         h = jax.nn.silu(grouped(xs, ex["gate"])) * grouped(xs, ex["up"])
-        ys = grouped(h, ex["down"])
+        ys = grouped_matmul(h, ex["down"], sizes)
     with obs.scope("dispatch"):
-        ys = ys.astype(jnp.float32) * wgt[:, None]
-        y = jnp.zeros((t, d), acc_dt).at[tok].add(ys.astype(acc_dt))
+        y = _combine(ys, gates.astype(acc_dt).T, held, slot, inv)
     stats = {"rows_here": sizes.sum(), "max_rows": sizes.max(),
              "dropped": jnp.zeros((), jnp.int32)}
     return y, stats
@@ -274,6 +346,7 @@ def _capacity(p, xf, idx, gates, cfg: ModelConfig, acc_dt):
     g = max(1, cfg.moe_dispatch_groups)
     assert t % g == 0, (t, g)
     cap = int(max(1, -(-t * k * cfg.capacity_factor // (e * g))))
+    obs.count("moe.capacity")
     with obs.scope("dispatch"):
         xg, tok, wgt, sizes = jax.vmap(
             lambda xfg, ig, wg: _capacity_group(xfg, ig, wg, e, cap)

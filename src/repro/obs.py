@@ -9,8 +9,9 @@ each compiled instruction carries the innermost scope in its ``op_name``
 ``tf_op``.  They change no instruction.
 
 Counts are kept at trace time: :func:`count` adds one each time traced code
-passes it (``attention.kernel``, ``attention.blockwise``), so a jitted
-function counts once per trace, not once per call.
+passes it (``attention.kernel``, ``attention.blockwise``, ``moe.dropless``,
+``moe.capacity``), so a jitted function counts once per trace, not once per
+call.
 
 The compile log keeps, for each top-level jitted function, the seconds it
 spent tracing, lowering and compiling (or loading from JAX's persistent
@@ -34,8 +35,9 @@ SCOPES = (
     "attention",    # the attention kernel: forward, recompute, backward
     "mlp",          # the SwiGLU MLP, and a MoE layer's shared experts
     "router",       # a MoE router: scores, selection, gates, balance loss
-    "dispatch",     # token copies sorted to their experts, gathered, and
-                    # scatter-added back weighted (the combine)
+    "dispatch",     # token copies sorted to their experts and gathered
+                    # there, then gathered back and summed, weighted, over
+                    # each token's copies (the combine)
     "experts",      # the held experts' grouped matmuls
     "head_loss",    # logits and cross-entropy
     "optimizer",    # gradient clipping and the optimizer's update

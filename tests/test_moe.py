@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.test_util import check_grads
 
 from repro.configs import get
 from repro.launch.steps import make_train_step
@@ -94,6 +95,147 @@ def test_megablox_lowering_matches_ragged_dot(monkeypatch, skew):
         assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
                                    rtol=1e-4)
+
+
+def _no_bias(cfg):
+    return None
+
+
+def _held_bias(cfg):
+    # every token to held experts 0, 1 and 2
+    return jnp.zeros(cfg.n_router).at[:3].set(100.0)
+
+
+def _absent_bias(cfg):
+    # every token to absent experts: no copy is held
+    e = cfg.n_experts
+    return jnp.zeros(cfg.n_router).at[e:e + cfg.top_k].set(100.0)
+
+
+@pytest.mark.parametrize("bias, remat", [
+    (_no_bias, False), (_held_bias, False), (_absent_bias, False),
+    (_no_bias, True)], ids=["uniform", "all_held", "none_held", "remat"])
+def test_dropless_gradients_match_the_dense_reference(bias, remat):
+    # the gathers both ways (and their custom gradients) against every held
+    # expert over every token, in float32: the gradients of the input, the
+    # router and the three expert weights
+    cfg = SMOKE
+    p, x = _layer(cfg)
+    if bias(cfg) is not None:
+        p["router_bias"] = bias(cfg)
+    r = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def layer(p, x):
+        return moe.moe_apply(p, x, cfg)[0]
+
+    def dense(p, x):
+        idx, gates, _, _ = moe.route(p, x, cfg)
+        xf = x.reshape(-1, cfg.d_model)
+        return _dense_routed(p, x, idx, gates, cfg) \
+            + layers.mlp_apply(p["shared"], xf)
+
+    if remat:
+        layer = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.nothing_saveable)
+
+    def grads(f):
+        return jax.grad(lambda p, x: jnp.sum(f(p, x).reshape(r.shape) * r),
+                        (0, 1))(p, x)
+
+    (gp, gx), (wp, wx) = grads(layer), grads(dense)
+    held = np.asarray(moe.route(p, x, cfg)[0]) < cfg.n_experts
+    if bias is _held_bias:
+        assert held.all()
+    elif bias is _absent_bias:
+        assert not held.any()
+    else:
+        assert held.any() and not held.all()
+    pairs = [(gx, wx), (gp["router"], wp["router"])] + [
+        (gp["experts"][w], wp["experts"][w]) for w in ("gate", "up", "down")]
+    for got, want in pairs:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-4)
+    if bias is _absent_bias:
+        for w in ("gate", "up", "down"):
+            assert not np.any(np.asarray(gp["experts"][w]))
+
+
+def _slots(key, n, k):
+    """A random order of n tokens' k copies, as ``_dropless`` gives it to
+    the gathers: each sorted copy's slot-major place, and the inverse."""
+    order = jax.random.permutation(key, n * k)
+    slot = (order % k) * n + order // k
+    return slot, jnp.zeros_like(slot).at[slot].set(jnp.arange(n * k))
+
+
+@pytest.mark.parametrize("k", [1, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_copies_match_plain_indexing(k, dtype):
+    # values and gradient of the custom-gradient gather against autodiff of
+    # x[slot % n] (a scatter-add over each token's repeated row), masked to
+    # the held copies; the cotangent of a copy not held holds NaN, as the
+    # TPU's unwritten gradient rows may, and reaches no token
+    n, d = 16, 8
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d)).astype(dtype)
+    slot, inv = _slots(jax.random.PRNGKey(1), n, k)
+    held = jnp.ones((k, n), bool).at[0, :4].set(False)
+    here = held.reshape(-1)[slot][:, None]
+    nan_rows = inv.reshape(k, n)[0, :4]
+    g = jax.random.normal(jax.random.PRNGKey(2), (n * k, d)).astype(dtype)
+
+    def copies(x):
+        return moe._copies(x, slot, inv, held)
+
+    got, got_vjp = jax.vjp(copies, x)
+    want, want_vjp = jax.vjp(
+        lambda x: jnp.where(here, x.astype(jnp.float32)[slot % n], 0), x)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want))
+    assert not np.any(np.asarray(got, np.float32)[nan_rows])
+    (gx,) = got_vjp(g.at[nan_rows].set(jnp.nan))
+    (wx,) = want_vjp(g.astype(jnp.float32))
+    assert gx.dtype == dtype
+    tol = 1e-6 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(gx, np.float32), np.asarray(wx),
+                               atol=tol, rtol=tol)
+    check_grads(lambda x: moe._copies(x, slot, inv, held),
+                (x.astype(jnp.float32),), order=1, modes=["rev"])
+
+
+@pytest.mark.parametrize("k", [1, 3])
+def test_combine_matches_plain_indexing(k):
+    # the weighted sum over each token's held copies, and its gradients in
+    # the results and the weights, against autodiff of plain indexing; a
+    # copy not held reads a row that holds NaN, as the TPU's unwritten rows
+    # may, and gives nothing and takes a gradient of 0; a held copy of
+    # weight 0 still takes its weight's gradient
+    n, d = 16, 8
+    slot, inv = _slots(jax.random.PRNGKey(1), n, k)
+    ys = jax.random.normal(jax.random.PRNGKey(0), (n * k, d))
+    w = jax.random.uniform(jax.random.PRNGKey(3), (k, n)).at[-1, 5].set(0.0)
+    held = jnp.ones((k, n), bool).at[0, :4].set(False)
+    nan_rows = inv.reshape(k, n)[0, :4]
+    g = jax.random.normal(jax.random.PRNGKey(2), (n, d))
+
+    def plain(ys, w):
+        back = ys[inv].reshape(k, n, d)
+        return jnp.sum(back * (w * held)[..., None], axis=0)
+
+    def combine(ys, w):
+        return moe._combine(ys, w, held, slot, inv)
+
+    got, got_vjp = jax.vjp(combine, ys.at[nan_rows].set(jnp.nan), w)
+    want, want_vjp = jax.vjp(plain, ys.at[nan_rows].set(0.0), w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6,
+                               atol=1e-6)
+    for a, b in zip(got_vjp(g), want_vjp(g)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
+    g_ys, g_w = got_vjp(g)
+    assert not np.any(np.asarray(g_ys)[nan_rows])
+    assert np.asarray(g_w)[-1, 5] != 0
+    check_grads(combine, (ys, w), order=1, modes=["rev"])
 
 
 def test_bias_chooses_but_does_not_gate():

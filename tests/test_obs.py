@@ -138,6 +138,45 @@ def test_moe_scopes_reach_the_compiled_step(moe_compiled_text, name):
     assert found == {"forward": True, "backward": True}
 
 
+def _scatters(text):
+    """(output dimensions, op name) of every scatter instruction of a
+    compiled module's text, fused or not."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* scatter\(",
+                     line)
+        if m:
+            found.append((m.group(1), _op_name(line)))
+    return found
+
+
+def test_moe_dispatch_moves_token_rows_by_gathers_only(moe_compiled_text):
+    # dispatch_ms.train: the copies go to their experts and back by gathers,
+    # forward and backward; a scatter there builds only a rank-1 index
+    # (the group sizes' bincount, the inverse permutation)
+    scatters = [(dims, name) for dims, name in _scatters(moe_compiled_text)
+                if name and obs.innermost(name) == "dispatch"]
+    assert scatters
+    for dims, name in scatters:
+        assert len(dims.split(",")) < 2, (dims, name)
+
+
+def test_moe_train_step_takes_the_dropless_dispatch():
+    cfg = get("moonlight_16b_a3b", smoke=True)
+    params = jax.eval_shape(
+        lambda: model_api(cfg).init(jax.random.PRNGKey(0), cfg))
+    before = obs.counts()
+    jax.jit(make_train_step(cfg, OPT)).trace(
+        params, jax.eval_shape(OPT.init, params), _batch(2, 32))
+    after = obs.counts()
+
+    def traced(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    assert traced("moe.dropless") >= 1
+    assert traced("moe.capacity") == 0
+
+
 def _custom_call_names(module_text):
     """{kernel name: full op name} of the Mosaic calls in a lowered
     module's text (``as_text(debug_info=True)``).  A call's location names
